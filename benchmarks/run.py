@@ -589,6 +589,9 @@ def trace_smoke():
 
 
 def main(argv=None) -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--tune", action="store_true",
                     help="include the Plan-IR autotuner section")

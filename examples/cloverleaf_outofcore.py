@@ -9,10 +9,12 @@ achieved-bandwidth metric vs. the resident baseline.
 import numpy as np
 
 from repro.apps import CloverLeaf2D
+from repro.compile_cache import enable_compile_cache
 from repro.core import P100_NVLINK, Session
 
 
 def main():
+    enable_compile_cache()
     capacity = 4 << 20               # scaled-down "16 GB"
     nx = 450                         # ~3x capacity with 25 fp32 datasets
     app_probe = CloverLeaf2D(nx, nx)

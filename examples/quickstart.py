@@ -13,6 +13,7 @@ identical chains replay a cached plan.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import Block, Session, TPU_V5E, make_dataset
 from repro.kernels import star2d_kernel
 
@@ -34,6 +35,7 @@ def heat(sess: Session, n=512, m=256, steps=8):
 
 
 def main():
+    enable_compile_cache()
     ref = heat(Session("reference"))
 
     # fast memory holds only ~1/4 of the problem: out-of-core streaming

@@ -14,6 +14,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_reduced_config  # noqa: E402
 from repro.models import decode_step, init_params  # noqa: E402
 from repro.models.offload import StreamedDecoder  # noqa: E402
@@ -21,6 +22,7 @@ from repro.models.transformer import init_cache  # noqa: E402
 
 
 def main():
+    enable_compile_cache()
     cfg = get_reduced_config("llama3_2_1b").with_(num_layers=8)
     key = jax.random.PRNGKey(0)
     params = init_params(cfg, key)

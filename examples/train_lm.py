@@ -19,6 +19,7 @@ sys.path.insert(0, "src")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 from repro.configs import get_reduced_config  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.models import init_params  # noqa: E402
@@ -40,6 +41,7 @@ def build_config(preset: str):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", choices=["tiny", "100m"], default="tiny")
     ap.add_argument("--steps", type=int, default=None)

@@ -15,6 +15,7 @@ datasets with a very poor copy/compute overlap".
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
@@ -80,6 +81,7 @@ class CloverLeaf2D:
         self.S_adv_y = offset_stencil((0, -2), (0, -1), (0, 0), (0, 1), (0, 2))
         self.step_count = 0
         self.dt = 1e-4
+        self.step_walls: List[float] = []
 
     # -- helpers --------------------------------------------------------------
     def _interior(self):
@@ -454,22 +456,31 @@ class CloverLeaf2D:
         return [s.name for s in specs]
 
     def run(self, rt: Session, steps: int, dt_every: bool = True) -> Dict[str, float]:
-        """Full driver: init, then per-step chains with the paper's breakers."""
+        """Full driver: init, then per-step chains with the paper's breakers.
+
+        ``step_walls`` gets each step's host wall-clock seconds, measured
+        from one ``dt`` read to the next (the last step ends at the final
+        flush).  Each mark follows a host read of device results, so the
+        device work before it is complete."""
         self.record_init(rt)
         rt.flush()
         rt.cyclic = True  # paper §4.1: set after the initialisation phase
         out: Dict[str, float] = {}
+        marks = []
         for s in range(steps):
             self._ideal_gas(rt, "density0", "energy0", "_dt")
             self._viscosity(rt)
             self._calc_dt(rt)
             if dt_every:
                 self.dt = float(min(1e-4, rt.reduction("dt")))  # chain breaker
+            marks.append(time.perf_counter())
             self.record_timestep(rt)
             if self.summary_every and (s + 1) % self.summary_every == 0:
                 for name in self.record_summary(rt):
                     out[name] = float(rt.reduction(name))
         rt.flush()
+        marks.append(time.perf_counter())
+        self.step_walls = [b - a for a, b in zip(marks, marks[1:])]
         return out
 
     def total_bytes(self) -> int:

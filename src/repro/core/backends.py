@@ -46,7 +46,7 @@ Register your own with::
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -102,10 +102,13 @@ class PallasBackend:
     :func:`repro.kernels.star2d_kernel` / ``star3d_kernel``) execute through
     the Pallas TPU kernels (``stencil2d``/``stencil3d``); untagged loops fall
     back to the reference path, so arbitrary chains still run correctly.
+    ``fallback_loops`` counts those.  ``interpret`` is passed to the kernels
+    (None: compiled on a TPU, interpreted on the CPU).
     """
 
-    def __init__(self):
+    def __init__(self, interpret: Optional[bool] = None):
         self.history: List = []
+        self.interpret = interpret
         self.pallas_loops = 0
         self.fallback_loops = 0
 
@@ -145,7 +148,8 @@ class PallasBackend:
 
         fn = kernels.stencil2d if kind == "stencil2d" else kernels.stencil3d
         padded = np.ascontiguousarray(src_dat.read(halo_box))
-        out = fn(padded, np.asarray(coeffs, np.float32))
+        out = fn(padded, np.asarray(coeffs, np.float32),
+                 interpret=self.interpret)
         dst_dat.write(box, np.asarray(out, dtype=dst_dat.dtype))
         return True
 

@@ -166,6 +166,21 @@ def analyze_chain(loops: Sequence[ParallelLoop], tiled_dim: int = 0) -> ChainInf
     )
 
 
+def chain_live_set(loops: Sequence[ParallelLoop]) -> frozenset:
+    """Datasets the §4.1 cyclic elision may NOT touch in a piece of
+    ``loops`` (a split half or a mesh segment): everything that is not
+    write-first over the *whole* chain.  A piece's local classification can
+    turn a chain-read-first dataset (``reset_field`` writing ``xvel0`` in the
+    last piece) into a write-first one — eliding its download would leave
+    the home rows stale, which cyclic on the unsplit chain would never do."""
+    first: Dict[str, bool] = {}
+    for lp in loops:
+        for a in lp.args:
+            if a.dat.name not in first:
+                first[a.dat.name] = not a.mode.reads
+    return frozenset(n for n, wf in first.items() if not wf)
+
+
 def chain_signature(info: ChainInfo) -> Tuple:
     """A structural fingerprint of a chain: used by speculative prefetching
     (§4.1) to guess whether the next chain 'looks like' the previous one, and

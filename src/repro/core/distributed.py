@@ -30,7 +30,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import axis_size, shard_map
 from .dependency import analyze_chain
 from .loop import ParallelLoop
 
@@ -64,7 +63,7 @@ def exchange_halos(arrays: Dict[str, jax.Array], depth: int, axis_name: str,
     """
     if depth <= 0:
         return dict(arrays)
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if periodic:
         fwd = [(i, (i + 1) % n) for i in range(n)]
         bwd = [(i, (i - 1) % n) for i in range(n)]
@@ -173,7 +172,7 @@ def make_sharded_chain_step(
 
     spec = P(*[None if d != dim else axis_name for d in range(2)])
     # A single PartitionSpec broadcasts over the dict-of-arrays pytree.
-    shard_fn = shard_map(
+    shard_fn = jax.shard_map(
         local, mesh=mesh, in_specs=spec, out_specs=spec, check_vma=False
     )
     jitted = jax.jit(shard_fn)

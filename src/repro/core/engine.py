@@ -141,17 +141,23 @@ class TileEngine:
 
         return jax.jit(tile_fn)
 
+    def program(self, tile: TilePlan):
+        """The jitted tile function for ``tile``'s signature, called as
+        ``fn(slots, starts, origins)`` (built on first use, then cached)."""
+        sig = self._signature(tile)
+        fn = self._cache.get(sig)
+        if fn is None:
+            fn = self._build(sig)
+            self._cache[sig] = fn
+        return fn
+
     def run_tile(
         self,
         tile: TilePlan,
         slots: Dict[str, jax.Array],
         origins: Dict[str, int],
     ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
-        sig = self._signature(tile)
-        fn = self._cache.get(sig)
-        if fn is None:
-            fn = self._build(sig)
-            self._cache[sig] = fn
+        fn = self.program(tile)
         starts = {
             k: jnp.int32(box[self.td][0])
             for k, box in enumerate(tile.loop_ranges)

@@ -24,10 +24,11 @@ Since the Plan-IR redesign the executor is a thin planner/interpreter pair:
 ``ResidentExecutor`` — the paper's baseline: everything resident in fast
 memory for the whole run (raises, like the paper's segfault, if it can't fit).
 
-Data plane: home copies are NumPy (slow memory); slots are JAX device arrays;
-uploads/downloads go through ``jnp.asarray``/``np.asarray`` so the data path
-is real on every backend, while *timings* for the paper's platforms come from
-the calibrated :class:`~repro.core.memory.HardwareModel` ledger.
+Data plane: home copies are NumPy (slow memory); slots are JAX device arrays
+on the executor's ``device``; uploads/downloads go through
+``jnp.asarray``/``np.asarray`` so the data path is real on every backend,
+while the *modelled* timings for the paper's platforms come from the
+:class:`~repro.core.memory.HardwareModel` ledger.
 """
 from __future__ import annotations
 
@@ -38,8 +39,9 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
 
 import numpy as np
 
-from .dependency import (ChainInfo, analyze_chain, chain_signature,
-                         plan_signature, shared_plan_signature)
+from .dependency import (ChainInfo, analyze_chain, chain_live_set,
+                         chain_signature, plan_signature,
+                         shared_plan_signature)
 from .engine import TileEngine
 from .interp import DataPlaneInterpreter, LedgerInterpreter, SpecState
 from .loop import ParallelLoop
@@ -134,6 +136,9 @@ class ChainStats:
     # executor).  Zero for unsharded chains.
     halo_messages: int = 0
     halo_bytes: int = 0
+    # Ids of the devices holding the chain's slot/pinned arrays at its end
+    # (data-plane runs; a sharded segment lists every shard's device).
+    devices: Tuple[int, ...] = ()
 
 
 @dataclass
@@ -160,8 +165,12 @@ class ChainPlan:
 class OutOfCoreExecutor:
     """Explicitly-managed 3-slot streaming executor (Algorithm 1)."""
 
-    def __init__(self, config: OOCConfig = None, *, shared_plans=None):
+    def __init__(self, config: OOCConfig = None, *, shared_plans=None,
+                 device=None):
         self.cfg = config or OOCConfig()
+        # The JAX device this executor stages onto and computes on (a
+        # sharded mesh gives each shard its own); None: JAX's default.
+        self.device = device
         # LRU-bounded: kernels capturing a per-step constant (a real dt
         # changing every step) legitimately produce a new plan per flush —
         # without a bound a long run would accumulate engines/ChainInfos
@@ -371,7 +380,9 @@ class OutOfCoreExecutor:
         Splitting breaks the §4.1 Cyclic contract: a write-first dat of the
         first half is no longer a dead temporary if the second half reads it,
         so its download cannot be elided — ``keep_live`` carries the dats the
-        remainder of the original chain still consumes."""
+        remainder of the original chain still consumes, and neither half may
+        elide a dataset the whole chain reads before writing
+        (:func:`~repro.core.dependency.chain_live_set`)."""
         try:
             return self._interpret_chain(loops, keep_live, plan, halo, warm)
         except MemoryError:
@@ -390,6 +401,7 @@ class OutOfCoreExecutor:
             # the three must stay in lock-step.
             head_writes = frozenset(
                 a.dat.name for lp in head for a in lp.args if a.mode.writes)
+            keep_live = keep_live | chain_live_set(loops)
             out = self.run_chain(head, keep_live | tail_reads, halo=halo,
                                  warm=warm)
             # Both halves may contribute to the same reduction: combine, not
@@ -457,7 +469,7 @@ class OutOfCoreExecutor:
                 codecs=resolve_codecs(cfg.codec, tuple(cp.info.datasets)),
                 halo_runtime=self.halo_runtime,
                 tracer=tr, trace_tag=self.trace_tag,
-                chain_index=chain_index)
+                chain_index=chain_index, device=self.device)
         res = interp.run()
         if tr.enabled:
             self.ledgers.append(res.ledger)
@@ -504,6 +516,7 @@ class OutOfCoreExecutor:
                 disk_written=disk_written,
                 halo_messages=res.halo_messages,
                 halo_bytes=res.halo_bytes,
+                devices=res.devices,
             )
         )
         return res.reductions

@@ -96,6 +96,9 @@ class InterpResult:
     # checks these against the runtime's achieved HaloExchangeStats.
     halo_messages: int = 0
     halo_bytes: int = 0
+    # Data plane: ids of the devices holding the chain's slot and pinned
+    # arrays at its end (empty in sim mode).
+    devices: Tuple[int, ...] = ()
 
 
 class LedgerInterpreter:
@@ -134,6 +137,7 @@ class LedgerInterpreter:
         self.prefetch_hits = 0
         self.disk_read = self.disk_written = 0
         self.halo_messages = self.halo_bytes = 0
+        self.devices: Tuple[int, ...] = ()
         self.reductions: Dict[str, np.ndarray] = {}
         # event-id cursors (the four-stream dependency wiring)
         self.last_upload_eid: Optional[int] = None
@@ -210,6 +214,7 @@ class LedgerInterpreter:
             ledger=self.ledger,
             disk_read=self.disk_read, disk_written=self.disk_written,
             halo_messages=self.halo_messages, halo_bytes=self.halo_bytes,
+            devices=self.devices,
         )
         if self.tracer.enabled and self._trace_modelled:
             self._emit_modelled_spans()
@@ -577,6 +582,9 @@ class DataPlaneInterpreter(LedgerInterpreter):
     resolved per-dataset codec map.  Ledger transfer events are recorded with
     raw sizes at submission (dependency wiring needs ids in submission order)
     and patched with achieved post-codec wire bytes after the engine drains.
+    ``device`` places every array the chain stages (slots, pinned arrays,
+    prefetch captures) and so the compiled tiles that consume them; None
+    keeps JAX's default device.
     """
 
     # Wall-clock spans (dispatch + lane); the ledger keeps the model.
@@ -589,7 +597,8 @@ class DataPlaneInterpreter(LedgerInterpreter):
                  = None,
                  tracer: Optional[AnyTracer] = None,
                  trace_tag: str = "",
-                 chain_index: int = 0):
+                 chain_index: int = 0,
+                 device: Optional[Any] = None):
         super().__init__(plan, hw, rm=rm, spec=spec,
                          datasets=cp.info.datasets,
                          tracer=tracer, trace_tag=trace_tag,
@@ -599,6 +608,7 @@ class DataPlaneInterpreter(LedgerInterpreter):
         # on a virtual mesh, exchange_halos/ppermute under shard_map on a
         # real one) exactly once per exchange epoch across all devices.
         self.halo_runtime = halo_runtime
+        self.device = device
         self.cp = cp
         self.info = cp.info
         self.sched = cp.sched
@@ -647,7 +657,8 @@ class DataPlaneInterpreter(LedgerInterpreter):
                 dat = self.info.datasets[name]
                 shape = list(dat.padded_shape)
                 shape[td] = ln
-                arrays[name] = jnp.zeros(tuple(shape), dtype=dat.dtype)
+                arrays[name] = jnp.zeros(tuple(shape), dtype=dat.dtype,
+                                         device=self.device)
             slot.arrays = arrays
 
     def finish(self) -> None:
@@ -701,9 +712,15 @@ class DataPlaneInterpreter(LedgerInterpreter):
                 if dat is None:
                     continue
                 self.spec.data[name] = [
-                    (iv, jnp.array(self._dat_np_region(dat, iv)), id(dat),
+                    (iv, jnp.array(self._dat_np_region(dat, iv),
+                                   device=self.device), id(dat),
                      dat.version)
                     for iv in ivs]
+        self.devices = tuple(sorted(
+            {d.id for slot in self.slots for a in slot.arrays.values()
+             for d in a.devices()}
+            | {d.id for a in self.pinned_arrays.values()
+               for d in a.devices()}))
 
     # -- pinned residency -----------------------------------------------------
     def pin_ensure(self, name: str, nb: int) -> Tuple[int, int]:
@@ -718,7 +735,8 @@ class DataPlaneInterpreter(LedgerInterpreter):
             self.pinned_origins[name] = origin
             return 0, 0
         dec, raw, wire = self.codecs[name].roundtrip(dat.materialize())
-        arr = jnp.asarray(np.asarray(dec, dtype=dat.dtype))
+        arr = jnp.asarray(np.asarray(dec, dtype=dat.dtype),
+                          device=self.device)
         self.rm.pinned_store(dat, arr, origin)
         self.pinned_arrays[name] = arr
         self.pinned_origins[name] = origin
@@ -809,6 +827,7 @@ class DataPlaneInterpreter(LedgerInterpreter):
         codecs = self.codecs
         slot_slice = self._slot_slice
         dat_np_region = self._dat_np_region
+        device = self.device
 
         def task() -> Tuple[int, int]:
             raw = wire = 0
@@ -829,7 +848,8 @@ class DataPlaneInterpreter(LedgerInterpreter):
                 dec, r, w = codecs[name].roundtrip(chunk)
                 raw += r
                 wire += w
-                vals = jnp.asarray(np.asarray(dec, dtype=dat.dtype))
+                vals = jnp.asarray(np.asarray(dec, dtype=dat.dtype),
+                                   device=device)
                 lo, hi = use.lo - org[name], use.hi - org[name]
                 # Disjoint-region updates commute, but the functional
                 # read-modify-write of the slot's dict entry must be atomic

@@ -75,15 +75,13 @@ class DeviceMesh:
     def spec(self) -> str:
         return f"{self.kind}:{self.num_devices}"
 
-    def jax_mesh(self):
-        """The concrete ``jax.sharding.Mesh`` over the first ``num_devices``
-        devices (``kind="jax"`` only)."""
+    def jax_devices(self) -> list:
+        """The first ``num_devices`` JAX devices, in mesh order
+        (``kind="jax"`` only)."""
         if self.kind != "jax":
             raise MeshError(f"{self.spec!r} is a virtual mesh; only "
-                            f"kind='jax' meshes materialise jax.sharding.Mesh")
+                            f"kind='jax' meshes map onto JAX devices")
         import jax
-        import numpy as np
-        from jax.sharding import Mesh
 
         devs = jax.devices()
         if len(devs) < self.num_devices:
@@ -91,7 +89,14 @@ class DeviceMesh:
                 f"mesh {self.spec!r} needs {self.num_devices} JAX devices, "
                 f"only {len(devs)} available (set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count=N for CPU testing)")
-        return Mesh(np.asarray(devs[: self.num_devices]), (self.axis_name,))
+        return devs[: self.num_devices]
+
+    def jax_mesh(self):
+        """The concrete ``jax.sharding.Mesh`` over :meth:`jax_devices`."""
+        import numpy as np
+        from jax.sharding import Mesh
+
+        return Mesh(np.asarray(self.jax_devices()), (self.axis_name,))
 
 
 def parse_mesh(spec: Union[None, int, str, DeviceMesh]) -> Optional[DeviceMesh]:

@@ -37,7 +37,7 @@ import numpy as np
 from .backends import make_backend
 from .block import Block
 from .dataset import Dataset
-from .dependency import kernel_fingerprint
+from .dependency import chain_live_set, kernel_fingerprint
 from .loop import AccessMode, Accessor, Arg, Kernel, ParallelLoop, ReductionSpec
 from .memory import PRESETS, TPU_V5E, HardwareModel
 from .stencil import Stencil, offset_stencil, point_stencil
@@ -615,6 +615,7 @@ class Session:
                 a.dat.name for lp in tail for a in lp.args if a.mode.reads)
             head_writes = frozenset(
                 a.dat.name for lp in head for a in lp.args if a.mode.writes)
+            keep_live = keep_live | chain_live_set(loops)
             return (self._plan_split(ex, head, keep_live | tail_reads, warm)
                     + self._plan_split(ex, tail, keep_live,
                                        warm | head_writes))

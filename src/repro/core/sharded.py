@@ -46,7 +46,7 @@ import numpy as np
 
 from .block import Block
 from .dataset import Dataset
-from .dependency import loop_kernel_fingerprint
+from .dependency import chain_live_set, loop_kernel_fingerprint
 from .distributed import HaloExchangeStats
 from .executor import ChainStats, OOCConfig, OutOfCoreExecutor
 from .loop import Accessor, Arg, ParallelLoop
@@ -248,10 +248,13 @@ class ShardedOutOfCoreExecutor:
         self.shard_dim = shard_dim
         self.halo_depth = halo_depth
         # The inner executors share THIS config object (the Session's cyclic
-        # toggle and tuner overrides reach every device).
+        # toggle and tuner overrides reach every device).  On a ``jax:N``
+        # mesh shard s stages onto and computes on mesh device s — the
+        # device whose block the halo collective exchanges for it.
+        devices = (self.mesh.jax_devices() if self.mesh.kind == "jax"
+                   else [None] * self.mesh.num_devices)
         self.inner: List[OutOfCoreExecutor] = [
-            OutOfCoreExecutor(self.cfg)
-            for _ in range(self.mesh.num_devices)
+            OutOfCoreExecutor(self.cfg, device=dev) for dev in devices
         ]
         # One tracing spine for the whole mesh: each device's executor emits
         # onto the shared tracer under a ``devN/`` track prefix (so Perfetto
@@ -595,7 +598,6 @@ class ShardedOutOfCoreExecutor:
         if fn is None:
             import jax
 
-            from ..compat import shard_map
             from .distributed import exchange_halos
 
             sd = state.shard_dim
@@ -610,7 +612,7 @@ class ShardedOutOfCoreExecutor:
                     out[nm] = got[nm]
                 return out
 
-            fn = jax.jit(shard_map(collective, mesh=mesh, in_specs=spec,
+            fn = jax.jit(jax.shard_map(collective, mesh=mesh, in_specs=spec,
                                    out_specs=spec, check_vma=False))
             state._collectives[key] = fn
         return fn
@@ -643,7 +645,7 @@ class ShardedOutOfCoreExecutor:
         reductions: Dict[str, np.ndarray] = {}
         modified: Set[str] = set()
         accessed: Set[str] = set()
-        not_elidable = self._chain_live_set(loops)
+        not_elidable = chain_live_set(loops)
         for i, seg in enumerate(segments):
             tail_reads = frozenset(
                 a.dat.name for later in segments[i + 1:] for lp in later
@@ -671,22 +673,6 @@ class ShardedOutOfCoreExecutor:
                 {a.dat.name for lp in local for a in lp.args
                  if a.mode.reads})))
         return locals_by_shard, names_by_shard
-
-    @staticmethod
-    def _chain_live_set(loops: Sequence[ParallelLoop]) -> frozenset:
-        """Datasets the §4.1 cyclic elision may NOT touch at segment level:
-        everything that is not write-first over the *whole* chain.  A
-        segment's local classification can turn a chain-read-first dataset
-        (``reset_field`` writing ``xvel0`` in the last segment) into a
-        segment-write-first one — eliding its download would leave the home
-        rows stale for the next chain's halo exchange, which ``ooc-cyclic``
-        on the unsegmented chain would never do."""
-        first: Dict[str, bool] = {}
-        for lp in loops:
-            for a in lp.args:
-                if a.dat.name not in first:
-                    first[a.dat.name] = not a.mode.reads
-        return frozenset(n for n, wf in first.items() if not wf)
 
     @staticmethod
     def _warm_set(local_seg, accessed_earlier: Set[str]) -> frozenset:
@@ -783,6 +769,7 @@ class ShardedOutOfCoreExecutor:
             disk_written=sum(c.disk_written for c in flat),
             halo_messages=sum(c.halo_messages for c in flat),
             halo_bytes=sum(c.halo_bytes for c in flat),
+            devices=tuple(sorted({d for c in flat for d in c.devices})),
         )
 
     # -- planning (Session.plan / explain / tune) ------------------------------
@@ -798,7 +785,7 @@ class ShardedOutOfCoreExecutor:
         segments = split_segments(loops, self.shard_dim, state.skirt)
         plans = []
         accessed: Set[str] = set(warm)
-        not_elidable = self._chain_live_set(loops)
+        not_elidable = chain_live_set(loops)
         for i, seg in enumerate(segments):
             tail_reads = frozenset(
                 a.dat.name for later in segments[i + 1:] for lp in later
@@ -842,6 +829,7 @@ class ShardedOutOfCoreExecutor:
             head_writes = frozenset(
                 a.dat.name for lp in head for a in lp.args
                 if a.mode.writes)
+            keep_live = keep_live | chain_live_set(local)
             return (self._plan_local(ex, head, keep_live | tail_reads,
                                      halo, warm)
                     + self._plan_local(ex, tail, keep_live, None,

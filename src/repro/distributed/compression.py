@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import axis_size, shard_map
-
 
 def _quantize(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12) / 127.0
@@ -33,7 +31,7 @@ def compressed_allreduce_mean(x: jax.Array, axis: str) -> jax.Array:
 
     x: identical-shape per-device local tensor (e.g. a gradient shard).
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     flat = x.reshape(-1)
     pad = (-flat.size) % n
     flat = jnp.pad(flat, (0, pad))
@@ -78,7 +76,7 @@ def make_pod_grad_allreduce(mesh: Mesh, compress: bool = True):
                     return compressed_allreduce_mean(gl, "pod")
                 return lax.pmean(gl, "pod")
 
-            return shard_map(
+            return jax.shard_map(
                 local, mesh=mesh,
                 in_specs=spec, out_specs=spec, check_vma=False,
             )(g)

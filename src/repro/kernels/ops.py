@@ -3,7 +3,6 @@ budgeting, and interpret-mode selection (interpret on CPU, compiled on TPU).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -14,43 +13,58 @@ from . import chain2d as _chain2d
 from . import stencil2d as _stencil2d
 from . import stencil3d as _stencil3d
 
-# Conservative VMEM working-set budget per block (bytes): v5e has ~128 MiB
-# VMEM; with double-buffered input+output blocks keep each block well under.
-_VMEM_BUDGET = 4 << 20
+# VMEM a kernel may hold: the Mosaic compiler's scoped-VMEM limit on v5e is
+# 16 MiB (its out-of-VMEM error reports "limit 16.00M"; the chip has 128 MiB
+# of VMEM in all).  Blocks are sized to 7/8 of it, leaving headroom for what
+# the estimate below does not see.
+_VMEM_LIMIT = 16 << 20
+_VMEM_BUDGET = _VMEM_LIMIT * 7 // 8
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU (tests), compiled on the TPU; any other
+    backend has no Pallas path here and is an error, never a silent
+    fallback to the interpreter."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default backend is {backend!r} — pass interpret= explicitly")
 
 
-def _pick_block_rows(h_rows: int, row_bytes: int, halo: int, budget: int) -> int:
-    """Largest power-of-two row count whose window fits the VMEM budget."""
-    bm = 1 << int(np.log2(max(1, budget // max(1, row_bytes))))
-    bm = max(8, min(bm, 512))
-    while bm > 8 and (bm + 2 * halo) * row_bytes > budget:
+def _tile_bytes(shape) -> int:
+    """f32 bytes of one VMEM buffer of ``shape`` after Mosaic's (8, 128)
+    tiling pads the last two dimensions."""
+    *lead, rows, lanes = shape
+    return (int(np.prod(lead, dtype=np.int64)) * (-(-rows // 8) * 8)
+            * (-(-lanes // 128) * 128) * 4)
+
+
+def _pick_block_rows(window_bytes, live: int, smallest: int = 8) -> int:
+    """Largest power-of-two block (``smallest``..512 rows or planes) whose
+    kernel fits the VMEM budget: 8 rows at least for a 2-D sublane block,
+    down to 1 plane for a 3-D z-slab.  ``window_bytes(bm)`` is one padded
+    input window of a ``bm``-row block; ``live`` is how many window-sized
+    buffers the kernel holds at once: the pipeline double-buffers the input
+    and the output window (4), and the fused K-sweep chain keeps two f32
+    intermediates of the window on top (6) — what the v5e compiler's
+    smallest sufficient scoped-VMEM limit showed at the widths
+    tests/test_tpu_compile.py compiles."""
+    bm = 512
+    while bm > smallest and live * window_bytes(bm) > _VMEM_BUDGET:
         bm //= 2
     return bm
 
 
-def _pad_rows(x: jax.Array, interior: int, halo: int, bm: int, axis: int = 0):
-    """Pad the interior row count to a multiple of bm (zeros; discarded)."""
-    rem = interior % bm
-    if rem == 0:
-        return x, interior
-    pad = bm - rem
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths), interior + pad
-
-
-@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def _stencil2d_jit(x, coeffs, block_rows, interpret):
-    H = x.shape[0] - 2
-    xp, Hp = _pad_rows(x, H, 1, block_rows)
-    out = _stencil2d.stencil2d_pallas(
-        xp, coeffs, block_rows=block_rows, interpret=interpret
-    )
-    return out[:H]
+_stencil2d_jit = jax.jit(_stencil2d.stencil2d_pallas,
+                         static_argnames=("block_rows", "interpret"))
+_stencil3d_jit = jax.jit(_stencil3d.stencil3d_pallas,
+                         static_argnames=("block_z", "interpret"))
+_chain2d_jit = jax.jit(_chain2d.chain2d_pallas,
+                       static_argnames=("steps", "block_rows", "interpret"))
 
 
 def stencil2d(x, coeffs, *, block_rows: Optional[int] = None,
@@ -60,19 +74,12 @@ def stencil2d(x, coeffs, *, block_rows: Optional[int] = None,
     coeffs = jnp.asarray(coeffs, dtype=jnp.float32)
     H, Wp = x.shape[0] - 2, x.shape[1]
     if block_rows is None:
-        block_rows = _pick_block_rows(H, Wp * x.dtype.itemsize, 1, _VMEM_BUDGET)
-    block_rows = min(block_rows, H)
+        block_rows = _pick_block_rows(
+            lambda bm: _tile_bytes((_stencil2d.window_rows(bm, 1), Wp)), 4)
     if interpret is None:
         interpret = _default_interpret()
-    return _stencil2d_jit(x, coeffs, block_rows, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("block_z", "interpret"))
-def _stencil3d_jit(x, coeffs, block_z, interpret):
-    D = x.shape[0] - 2
-    xp, Dp = _pad_rows(x, D, 1, block_z)
-    out = _stencil3d.stencil3d_pallas(xp, coeffs, block_z=block_z, interpret=interpret)
-    return out[:D]
+    return _stencil2d_jit(x, coeffs, block_rows=min(block_rows, H),
+                          interpret=interpret)
 
 
 def stencil3d(x, coeffs, *, block_z: Optional[int] = None,
@@ -81,23 +88,13 @@ def stencil3d(x, coeffs, *, block_z: Optional[int] = None,
     x = jnp.asarray(x)
     coeffs = jnp.asarray(coeffs, dtype=jnp.float32)
     D = x.shape[0] - 2
-    plane_bytes = x.shape[1] * x.shape[2] * x.dtype.itemsize
     if block_z is None:
-        block_z = _pick_block_rows(D, plane_bytes, 1, _VMEM_BUDGET)
-    block_z = min(block_z, D)
+        block_z = _pick_block_rows(
+            lambda bz: _tile_bytes((bz + 2,) + x.shape[1:]), 4, smallest=1)
     if interpret is None:
         interpret = _default_interpret()
-    return _stencil3d_jit(x, coeffs, block_z, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("steps", "block_rows", "interpret"))
-def _chain2d_jit(x, coeffs, steps, block_rows, interpret):
-    H = x.shape[0] - 2 * steps
-    xp, Hp = _pad_rows(x, H, steps, block_rows)
-    out = _chain2d.chain2d_pallas(
-        xp, coeffs, steps=steps, block_rows=block_rows, interpret=interpret
-    )
-    return out[:H]
+    return _stencil3d_jit(x, coeffs, block_z=min(block_z, D),
+                          interpret=interpret)
 
 
 def chain2d(x, coeffs, steps: int, *, block_rows: Optional[int] = None,
@@ -107,11 +104,13 @@ def chain2d(x, coeffs, steps: int, *, block_rows: Optional[int] = None,
     coeffs = jnp.asarray(coeffs, dtype=jnp.float32)
     H, Wp = x.shape[0] - 2 * steps, x.shape[1]
     if block_rows is None:
-        block_rows = _pick_block_rows(H, Wp * x.dtype.itemsize, steps, _VMEM_BUDGET)
-    block_rows = min(block_rows, H)
+        block_rows = _pick_block_rows(
+            lambda bm: _tile_bytes((_stencil2d.window_rows(bm, steps), Wp)),
+            4 if steps == 1 else 6)
     if interpret is None:
         interpret = _default_interpret()
-    return _chain2d_jit(x, coeffs, steps, block_rows, interpret)
+    return _chain2d_jit(x, coeffs, steps=steps,
+                        block_rows=min(block_rows, H), interpret=interpret)
 
 
 # -- declarative star-sweep kernels (the "pallas" backend's fast path) -----------

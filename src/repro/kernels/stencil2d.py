@@ -16,20 +16,36 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._compat import overlapping_spec
+
+def window_rows(block_rows: int, halo: int) -> int:
+    """Rows of one input window: the block plus its halos, rounded up to the
+    8-row sublane tile Mosaic requires of a block's second-minor dimension."""
+    return -(-(block_rows + 2 * halo) // 8) * 8
 
 
-def _kernel(x_ref, c_ref, o_ref, *, halo: int):
-    u = x_ref[...].astype(jnp.float32)
+def row_windows(x: jax.Array, block_rows: int, halo: int):
+    """Pad ``x`` (H + 2*halo padded rows) so that H is a multiple of
+    ``block_rows`` and the last window stays in bounds; returns the padded
+    array, the padded interior row count and the window's row count."""
+    H = x.shape[0] - 2 * halo
+    Hb = -(-H // block_rows) * block_rows
+    win = window_rows(block_rows, halo)
+    pad = Hb - block_rows + win - x.shape[0]
+    if pad > 0:
+        x = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1))
+    return x, Hb, win
+
+
+def _kernel(x_ref, c_ref, o_ref, *, rows: int):
+    u = x_ref[0:rows + 2, :].astype(jnp.float32)
     c0 = c_ref[0]
     cx = c_ref[1]
     cy = c_ref[2]
-    h = halo
-    core = u[h:-h, h:-h]
-    up = u[h - 1:-h - 1, h:-h]
-    dn = u[h + 1:u.shape[0] - h + 1, h:-h]
-    lf = u[h:-h, h - 1:-h - 1]
-    rt = u[h:-h, h + 1:u.shape[1] - h + 1]
+    core = u[1:-1, 1:-1]
+    up = u[:-2, 1:-1]
+    dn = u[2:, 1:-1]
+    lf = u[1:-1, :-2]
+    rt = u[1:-1, 2:]
     o_ref[...] = (c0 * core + cx * (up + dn) + cy * (lf + rt)).astype(o_ref.dtype)
 
 
@@ -37,8 +53,8 @@ def stencil2d_pallas(
     x: jax.Array,
     coeffs: jax.Array,
     *,
-    block_rows: int = 256,
-    interpret: bool = True,
+    block_rows: int,
+    interpret: bool,
 ) -> jax.Array:
     """Apply the 5-point stencil to ``x`` (padded by 1 halo cell per side).
 
@@ -48,24 +64,20 @@ def stencil2d_pallas(
     Returns:
       (H, W) updated interior.
     """
-    halo = 1
-    Hp, Wp = x.shape
-    H, W = Hp - 2 * halo, Wp - 2 * halo
+    H, Wp = x.shape[0] - 2, x.shape[1]
+    W = Wp - 2
     bm = min(block_rows, H)
-    # grid must cover H exactly; ops.py pads rows to a multiple of bm.
-    assert H % bm == 0, (H, bm)
-    grid = (H // bm,)
-    return pl.pallas_call(
-        functools.partial(_kernel, halo=halo),
-        out_shape=jax.ShapeDtypeStruct((H, W), x.dtype),
-        grid=grid,
+    xp, Hb, win = row_windows(x, bm, 1)
+    out = pl.pallas_call(
+        functools.partial(_kernel, rows=bm),
+        out_shape=jax.ShapeDtypeStruct((Hb, W), x.dtype),
+        grid=(Hb // bm,),
         in_specs=[
-            overlapping_spec(
-                (bm + 2 * halo, Wp),
-                lambda i: (i * bm, 0),
-            ),
+            pl.BlockSpec((pl.Element(win), pl.Element(Wp)),
+                         lambda i: (i * bm, 0)),
             pl.BlockSpec((3,), lambda i: (0,)),  # coefficients, replicated
         ],
         out_specs=pl.BlockSpec((bm, W), lambda i: (i, 0)),
         interpret=interpret,
-    )(x, coeffs)
+    )(xp, coeffs)
+    return out[:H]

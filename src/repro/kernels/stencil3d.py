@@ -9,27 +9,22 @@ u'[k,i,j] = c0*u[kij] + cz*(u[k±1]) + cx*(u[i±1]) + cy*(u[j±1])
 """
 from __future__ import annotations
 
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ._compat import overlapping_spec
 
-
-def _kernel(x_ref, c_ref, o_ref, *, halo: int):
+def _kernel(x_ref, c_ref, o_ref):
     u = x_ref[...].astype(jnp.float32)
-    h = halo
     c0, cz, cx, cy = c_ref[0], c_ref[1], c_ref[2], c_ref[3]
-    D0, D1, D2 = u.shape
-    core = u[h:-h, h:-h, h:-h]
-    zm = u[h - 1:D0 - h - 1, h:-h, h:-h]
-    zp = u[h + 1:D0 - h + 1, h:-h, h:-h]
-    xm = u[h:-h, h - 1:D1 - h - 1, h:-h]
-    xp = u[h:-h, h + 1:D1 - h + 1, h:-h]
-    ym = u[h:-h, h:-h, h - 1:D2 - h - 1]
-    yp = u[h:-h, h:-h, h + 1:D2 - h + 1]
+    core = u[1:-1, 1:-1, 1:-1]
+    zm = u[:-2, 1:-1, 1:-1]
+    zp = u[2:, 1:-1, 1:-1]
+    xm = u[1:-1, :-2, 1:-1]
+    xp = u[1:-1, 2:, 1:-1]
+    ym = u[1:-1, 1:-1, :-2]
+    yp = u[1:-1, 1:-1, 2:]
     o_ref[...] = (
         c0 * core + cz * (zm + zp) + cx * (xm + xp) + cy * (ym + yp)
     ).astype(o_ref.dtype)
@@ -39,26 +34,29 @@ def stencil3d_pallas(
     x: jax.Array,
     coeffs: jax.Array,
     *,
-    block_z: int = 8,
-    interpret: bool = True,
+    block_z: int,
+    interpret: bool,
 ) -> jax.Array:
-    """7-point stencil on ``x`` (padded by 1 per side); returns (D,H,W)."""
-    halo = 1
+    """7-point stencil on ``x`` (padded by 1 per side); returns (D,H,W).
+
+    The z window is a leading dimension, so it needs no sublane rounding;
+    only the plane dims are tiled by Mosaic and they stay whole."""
     Dp, Hp, Wp = x.shape
-    D, H, W = Dp - 2 * halo, Hp - 2 * halo, Wp - 2 * halo
+    D, H, W = Dp - 2, Hp - 2, Wp - 2
     bz = min(block_z, D)
-    assert D % bz == 0, (D, bz)
-    return pl.pallas_call(
-        functools.partial(_kernel, halo=halo),
-        out_shape=jax.ShapeDtypeStruct((D, H, W), x.dtype),
-        grid=(D // bz,),
+    Db = -(-D // bz) * bz
+    if Db > D:
+        x = jnp.pad(x, ((0, Db - D), (0, 0), (0, 0)))
+    out = pl.pallas_call(
+        _kernel,
+        out_shape=jax.ShapeDtypeStruct((Db, H, W), x.dtype),
+        grid=(Db // bz,),
         in_specs=[
-            overlapping_spec(
-                (bz + 2 * halo, Hp, Wp),
-                lambda i: (i * bz, 0, 0),
-            ),
+            pl.BlockSpec((pl.Element(bz + 2), pl.Element(Hp), pl.Element(Wp)),
+                         lambda i: (i * bz, 0, 0)),
             pl.BlockSpec((4,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((bz, H, W), lambda i: (i, 0, 0)),
         interpret=interpret,
     )(x, coeffs)
+    return out[:D]

@@ -133,6 +133,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Optional[str]
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

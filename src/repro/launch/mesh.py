@@ -7,21 +7,19 @@ used for coarse-grained parallelism only (extra DP with one grad all-reduce
 per step — optionally int8-compressed — or pipeline stages).
 
 Functions, not module constants: importing this module must never touch JAX
-device state (the dry-run sets XLA_FLAGS before first jax init).  Mesh
-construction goes through :mod:`repro.compat` so it works on JAX versions
-with and without ``jax.sharding.AxisType``.
+device state (the dry-run sets XLA_FLAGS before first jax init).  Every
+axis is in Auto mode.
 """
 from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -29,4 +27,5 @@ def make_host_mesh(model: int = 1):
     n = len(jax.devices())
     model = max(1, min(model, n))
     data = n // model
-    return make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

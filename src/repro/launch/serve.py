@@ -81,6 +81,9 @@ def stencil_main(argv=None):
 
 
 def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else list(argv)
     if argv and argv[0] == "stencil":
         return stencil_main(argv[1:])

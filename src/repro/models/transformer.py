@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-
-from ..compat import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .attention import (
@@ -269,7 +267,7 @@ def _moe_sublayer(blk, h, cfg: ModelConfig, mesh):
                 "w_gate": P(None, None), "w_up": P(None, None),
                 "w_down": P(None, None),
             }
-        out = shard_map(
+        out = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(param_specs, P(batch_axes, None, None)),
             out_specs=P(batch_axes, None, None),

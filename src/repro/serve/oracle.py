@@ -22,6 +22,7 @@ import threading
 from dataclasses import dataclass
 from typing import FrozenSet, List, Optional, Sequence, TYPE_CHECKING
 
+from repro.core.dependency import chain_live_set
 from repro.core.interp import predict_plans
 from repro.core.tune import make_sim_executor
 
@@ -110,5 +111,6 @@ class AdmissionOracle:
                 a.dat.name for lp in tail for a in lp.args if a.mode.reads)
             head_writes = frozenset(
                 a.dat.name for lp in head for a in lp.args if a.mode.writes)
+            keep_live = keep_live | chain_live_set(loops)
             return (self._plan_split(head, keep_live | tail_reads, warm)
                     + self._plan_split(tail, keep_live, warm | head_writes))

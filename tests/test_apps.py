@@ -28,6 +28,25 @@ class TestCloverLeaf2D:
         for k in ref_summary:
             np.testing.assert_allclose(ref_summary[k], summary[k], rtol=1e-3)
 
+    def test_split_chains_keep_carried_fields(self, cl2d_reference):
+        """Capacity a third of the working set splits the timestep chain on
+        MemoryError.  Under Cyclic a split half must not elide a field the
+        whole chain reads first (regression: ``reset_field`` in the tail
+        made ``xvel0`` look write-first there and its update was dropped,
+        leaving the boundary column at its initial value)."""
+        ref_app, ref_summary = cl2d_reference
+        app = CloverLeaf2D(40, 32, summary_every=3)
+        ex = OutOfCoreExecutor(OOCConfig(
+            capacity_bytes=app.total_bytes() / 3, prefetch=True))
+        summary = app.run(Runtime(ex), steps=3)
+        assert len(ex.history) > 3 * 2 + 1      # chains did split
+        for name in ("density0", "energy0", "xvel0", "yvel0"):
+            np.testing.assert_allclose(
+                ref_app.d(name).interior(), app.d(name).interior(),
+                rtol=1e-4, atol=1e-5, err_msg=name)
+        for k in ref_summary:
+            np.testing.assert_allclose(ref_summary[k], summary[k], rtol=1e-3)
+
     def test_dataset_count_matches_paper(self):
         assert len(CloverLeaf2D(16, 16).dats) == 25  # §5.1: 25 variables
 

@@ -12,10 +12,10 @@ _SCRIPT_HALO = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro.compat import make_mesh, shard_map
     from repro.core.distributed import exchange_halos, chain_halo_depth
 
-    mesh = make_mesh((8,), ("x",))
+    mesh = jax.make_mesh((8,), ("x",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     N, M, halo = 16, 64, 2
     per = M // 8
     rng = np.random.RandomState(0)
@@ -38,7 +38,7 @@ _SCRIPT_HALO = textwrap.dedent("""
             u = 0.5 * u + 0.25 * (jnp.roll(u, 1, 1) + jnp.roll(u, -1, 1))
         return {"u": u}
 
-    fn = jax.jit(shard_map(local, mesh=mesh, in_specs=P(None, "x"),
+    fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=P(None, "x"),
                            out_specs=P(None, "x"), check_vma=False))
     res = np.asarray(fn({"u": garr})["u"])
     outs = [res[:, r * (per + 2 * halo) + halo: r * (per + 2 * halo) + halo + per]
@@ -54,15 +54,15 @@ _SCRIPT_COMPRESS = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro.compat import make_mesh, shard_map
     from repro.distributed.compression import compressed_allreduce_mean
 
-    mesh = make_mesh((8,), ("pod",))
+    mesh = jax.make_mesh((8,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.RandomState(1)
     per_dev = rng.randn(8, 1000).astype(np.float32)
     x = jax.device_put(per_dev, NamedSharding(mesh, P("pod", None)))
 
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda g: compressed_allreduce_mean(g[0], "pod")[None],
         mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None),
         check_vma=False))
@@ -130,7 +130,6 @@ def test_exchange_halos_nonperiodic_keeps_edge_halos():
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.core.distributed import exchange_halos
 
     mesh = _make_mesh(2)
@@ -142,7 +141,7 @@ def test_exchange_halos_nonperiodic_keeps_edge_halos():
     garr = jax.device_put(stacked, NamedSharding(mesh, P(None, "x")))
 
     def run(periodic):
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             lambda a: exchange_halos({"u": a}, depth, "x", dim=1,
                                      periodic=periodic)["u"],
             mesh=mesh, in_specs=P(None, "x"), out_specs=P(None, "x"),

@@ -158,6 +158,13 @@ class TestShardedBackend:
         drive(sess, app)
         assert sess.backend.exchange_path == "ppermute"
         assert_all_dats_equal(ref, app)
+        # Each shard staged and computed on its own mesh device.
+        for s, ex in enumerate(sess.backend.inner):
+            assert ex.history
+            assert {c.devices for c in ex.history} == {
+                (jax.devices()[s].id,)}
+        assert sess.history[-1].devices == tuple(
+            d.id for d in jax.devices()[:4])
         st = sess.transfer_stats()
         assert st["halo_messages"] == sess.backend.halo_stats.messages
         assert st["halo_bytes"] == sess.backend.halo_stats.bytes
