@@ -70,8 +70,7 @@ def main():
     print(f"tiles          : {st.num_tiles}")
     print(f"uploaded       : {st.uploaded / 1e6:.1f} MB   "
           f"downloaded: {st.downloaded / 1e6:.1f} MB")
-    print(f"modelled step  : {st.modelled_s * 1e3:.2f} ms  "
-          f"-> {st.achieved_bw_model / 1e9:.0f} GB/s achieved (model: {hw.name})")
+    print(f"modelled step  : {st.modelled_s * 1e3:.2f} ms (model: {hw.name})")
     print(f"chain planning : {plan['plan_misses']} analysed, "
           f"{plan['plan_hits']} cache hits "
           f"({plan['plan_time_s'] * 1e3:.1f} ms total)")

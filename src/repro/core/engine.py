@@ -23,6 +23,7 @@ from jax import lax
 from .dependency import ChainInfo
 from .loop import AccessMode, Accessor, ParallelLoop
 from .tiling import TilePlan, TileSchedule
+from ..obs.tracer import AnyTracer, NULL_TRACER
 
 
 class _SliceAccessor(Accessor):
@@ -156,7 +157,16 @@ class TileEngine:
         tile: TilePlan,
         slots: Dict[str, jax.Array],
         origins: Dict[str, int],
+        *,
+        tracer: AnyTracer = NULL_TRACER,
+        track: str = "compute",
+        args: Optional[Dict] = None,
     ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
+        """Launch ``tile``'s program.  Traced, a signature seen for the first
+        time is lowered and compiled inside a ``tile_compile`` span (``args``
+        plus ``sig``, the signature's index in this engine) before the call,
+        which then finds it compiled."""
+        n_programs = len(self._cache)
         fn = self.program(tile)
         starts = {
             k: jnp.int32(box[self.td][0])
@@ -164,8 +174,8 @@ class TileEngine:
             if box is not None
         }
         origins_t = {name: jnp.int32(v) for name, v in origins.items()}
+        if tracer.enabled and len(self._cache) > n_programs:
+            with tracer.span("tile_compile", cat="compile", track=track,
+                             args={**(args or {}), "sig": n_programs}):
+                fn.lower(slots, starts, origins_t).compile()
         return fn(slots, starts, origins_t)
-
-    @property
-    def num_compiles(self) -> int:
-        return len(self._cache)

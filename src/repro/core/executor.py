@@ -109,7 +109,6 @@ class ChainStats:
     prefetch_hits: int
     wall_s: float
     modelled_s: float
-    achieved_bw_model: float   # loop_bytes / modelled makespan
     slot_bytes: int
     plan_cache_hit: bool = False   # chain plan replayed from cache
     plan_s: float = 0.0            # analysis + scheduling time (0 on hits)
@@ -228,7 +227,23 @@ class OutOfCoreExecutor:
         earlier segment landed real home data the §4.1 upload elision would
         let this segment's download clobber.
         Raises ``MemoryError`` (uncached) when no tile count fits, so
-        ``run_chain`` can split."""
+        ``run_chain`` can split.
+
+        Traced, the whole call is one ``plan`` span (``cat="plan"``, failed
+        fits included) with ``cache_hit`` among its args."""
+        tr = self.tracer
+        if not tr.enabled:
+            return self._plan_chain(loops, keep_live, halo, warm)
+        hits = self.plan_hits
+        args = {"chain": len(self.history), "cache_hit": False}
+        with tr.span("plan", cat="plan", track=self.trace_tag + "chain",
+                     args=args):
+            plan = self._plan_chain(loops, keep_live, halo, warm)
+            args["cache_hit"] = self.plan_hits > hits
+        return plan
+
+    def _plan_chain(self, loops: Sequence[ParallelLoop],
+                    keep_live: frozenset, halo, warm: frozenset) -> ChainPlan:
         cfg = self.cfg
         key = (plan_signature(loops, cfg.tiled_dim), cfg.num_tiles,
                cfg.num_slots, float(cfg.capacity), float(cfg.host_budget),
@@ -500,8 +515,6 @@ class OutOfCoreExecutor:
                 prefetch_hits=res.prefetch_hits,
                 wall_s=time.perf_counter() - t_wall,
                 modelled_s=res.makespan,
-                achieved_bw_model=(ir.loop_bytes / res.makespan
-                                   if res.makespan else 0.0),
                 slot_bytes=cp.slot_bytes,
                 plan_cache_hit=cache_hit,
                 plan_s=0.0 if cache_hit else cp.plan_s,
@@ -603,7 +616,6 @@ class ResidentExecutor:
         ledger = TransferLedger(self.hw)
         t = ledger.t_compute(last.loop_bytes, 0)
         last.modelled_s = max(t, 1e-30)
-        last.achieved_bw_model = last.loop_bytes / last.modelled_s
         return reds
 
     # plan-cache stats proxy to the inner executor (shared planner)

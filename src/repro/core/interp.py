@@ -47,7 +47,7 @@ from .tiling import Interval
 from .transfer import ResidencyManager, Slot
 from .transfer.engine import DISK, DOWN, UP
 from ..obs.audit import STREAM_NAMES
-from ..obs.tracer import AnyTracer, NULL_TRACER
+from ..obs.tracer import AnyTracer, NULL_SPAN, NULL_TRACER
 
 
 class _SimArray:
@@ -625,6 +625,10 @@ class DataPlaneInterpreter(LedgerInterpreter):
         self.red_specs = {r.name: r for lp in cp.info.loops
                           for r in lp.reductions}
         self._prefetch_armed = False
+        # Tracks of the runtime's spans: staging on the lanes' own tracks.
+        self.up_track = trace_tag + "upload"
+        self.down_track = trace_tag + "download"
+        self.compute_track = trace_tag + "compute"
 
     # -- home region helpers (store-routed: ram, mmap and chunked homes) -----
     def _dat_np_region(self, dat: Any, iv: Interval) -> np.ndarray:
@@ -711,11 +715,16 @@ class DataPlaneInterpreter(LedgerInterpreter):
                 dat = self.info.datasets.get(name)
                 if dat is None:
                     continue
-                self.spec.data[name] = [
-                    (iv, jnp.array(self._dat_np_region(dat, iv),
-                                   device=self.device), id(dat),
-                     dat.version)
-                    for iv in ivs]
+                captured = self.spec.data[name] = []
+                for iv in ivs:
+                    with (tr.span("stage_in", cat="stage",
+                                  track=self.up_track,
+                                  args=self._stage_args(name, iv.lo, iv.hi))
+                          if tr.enabled else NULL_SPAN):
+                        captured.append(
+                            (iv, jnp.array(self._dat_np_region(dat, iv),
+                                           device=self.device), id(dat),
+                             dat.version))
         self.devices = tuple(sorted(
             {d.id for slot in self.slots for a in slot.arrays.values()
              for d in a.devices()}
@@ -734,9 +743,14 @@ class DataPlaneInterpreter(LedgerInterpreter):
             self.pinned_arrays[name] = arr
             self.pinned_origins[name] = origin
             return 0, 0
-        dec, raw, wire = self.codecs[name].roundtrip(dat.materialize())
-        arr = jnp.asarray(np.asarray(dec, dtype=dat.dtype),
-                          device=self.device)
+        tr = self.tracer
+        with (tr.span("stage_in", cat="stage", track=self.up_track,
+                      args={"chain": self.chain_index, "dat": name,
+                            "bytes": nb})
+              if tr.enabled else NULL_SPAN):
+            dec, raw, wire = self.codecs[name].roundtrip(dat.materialize())
+            arr = jnp.asarray(np.asarray(dec, dtype=dat.dtype),
+                              device=self.device)
         self.rm.pinned_store(dat, arr, origin)
         self.pinned_arrays[name] = arr
         self.pinned_origins[name] = origin
@@ -816,7 +830,16 @@ class DataPlaneInterpreter(LedgerInterpreter):
             return iv, None  # stale capture: stage everything from home
         return iv, None
 
-    def _make_upload_task(self, slot: Slot, org: Dict[str, int],
+    def _stage_args(self, name: str, lo: int, hi: int,
+                    tile: Optional[int] = None) -> Dict[str, Any]:
+        """Args of a staging span: chain, tile, dataset and raw bytes."""
+        args: Dict[str, Any] = {"chain": self.chain_index, "dat": name,
+                                "bytes": self._nbytes(name, lo, hi)}
+        if tile is not None:
+            args["tile"] = tile
+        return args
+
+    def _make_upload_task(self, tile: int, slot: Slot, org: Dict[str, int],
                           items: List[Tuple[str, Interval]],
                           restores: List[Tuple]
                           ) -> Callable[[], Tuple[int, int]]:
@@ -828,6 +851,9 @@ class DataPlaneInterpreter(LedgerInterpreter):
         slot_slice = self._slot_slice
         dat_np_region = self._dat_np_region
         device = self.device
+        tr = self.tracer
+        lane = self.up_track
+        stage_args = self._stage_args
 
         def task() -> Tuple[int, int]:
             raw = wire = 0
@@ -843,21 +869,24 @@ class DataPlaneInterpreter(LedgerInterpreter):
                     slot.arrays[name] = dst.at[
                         slot_slice(dst, lo, hi, td)].set(vals)
             for name, use in items:
-                dat = info.datasets[name]
-                chunk = dat_np_region(dat, use)
-                dec, r, w = codecs[name].roundtrip(chunk)
-                raw += r
-                wire += w
-                vals = jnp.asarray(np.asarray(dec, dtype=dat.dtype),
-                                   device=device)
-                lo, hi = use.lo - org[name], use.hi - org[name]
-                # Disjoint-region updates commute, but the functional
-                # read-modify-write of the slot's dict entry must be atomic
-                # against the main thread's edge copy.
-                with slot.lock:
-                    arr = slot.arrays[name]
-                    slot.arrays[name] = arr.at[
-                        slot_slice(arr, lo, hi, td)].set(vals)
+                with (tr.span("stage_in", cat="stage", track=lane,
+                              args=stage_args(name, use.lo, use.hi, tile))
+                      if tr.enabled else NULL_SPAN):
+                    dat = info.datasets[name]
+                    chunk = dat_np_region(dat, use)
+                    dec, r, w = codecs[name].roundtrip(chunk)
+                    raw += r
+                    wire += w
+                    vals = jnp.asarray(np.asarray(dec, dtype=dat.dtype),
+                                       device=device)
+                    lo, hi = use.lo - org[name], use.hi - org[name]
+                    # Disjoint-region updates commute, but the functional
+                    # read-modify-write of the slot's dict entry must be
+                    # atomic against the main thread's edge copy.
+                    with slot.lock:
+                        arr = slot.arrays[name]
+                        slot.arrays[name] = arr.at[
+                            slot_slice(arr, lo, hi, td)].set(vals)
             return raw, wire
 
         return task
@@ -876,7 +905,7 @@ class DataPlaneInterpreter(LedgerInterpreter):
         if fh is not None:      # disk tier: rows must be host-resident first
             conflicts.append(fh)
         handle = self.tx.submit(
-            UP, self._make_upload_task(slot, org, items, restores),
+            UP, self._make_upload_task(op.tile, slot, org, items, restores),
             deps=conflicts)
         self.up_handles[op.tile] = handle
         for name, iv in items:
@@ -892,26 +921,40 @@ class DataPlaneInterpreter(LedgerInterpreter):
 
     # -- compute --------------------------------------------------------------
     def execute_tile(self, op: Compute, slot: Slot) -> None:
+        tr = self.tracer
+        track = self.compute_track
+        where = ({"chain": self.chain_index, "tile": op.tile}
+                 if tr.enabled else None)
         handle = self.up_handles.get(op.tile)
         if handle is not None:
-            handle.wait()   # tile's staging must have landed
+            # The tile's staging must have landed.
+            with (tr.span("upload_wait", cat="wait", track=track, args=where)
+                  if tr.enabled else NULL_SPAN):
+                handle.wait()
         tile = self.sched.tiles[op.tile]
         run_arrays = {**slot.arrays, **self.pinned_arrays}
         run_origins = {**self.origins[op.tile], **self.pinned_origins}
-        new_arrays, tile_reds = self.engine.run_tile(tile, run_arrays,
-                                                     run_origins)
+        with (tr.span("tile_dispatch", cat="dispatch", track=track,
+                      args=where) if tr.enabled else NULL_SPAN):
+            new_arrays, tile_reds = self.engine.run_tile(
+                tile, run_arrays, run_origins, tracer=tr, track=track,
+                args=where)
         for name in self.pinned_arrays:
             self.pinned_arrays[name] = new_arrays[name]
             self.rm.pinned_update(self.info.datasets[name], new_arrays[name])
         slot.arrays = {n: a for n, a in new_arrays.items()
                        if n not in self.pinned_arrays}
-        for name, val in tile_reds.items():
-            spec = self.red_specs[name]
-            if name in self.reductions:
-                self.reductions[name] = np.asarray(
-                    spec.combine(self.reductions[name], val))
-            else:
-                self.reductions[name] = np.asarray(val)
+        if not tile_reds:
+            return
+        with (tr.span("reduction_read", cat="wait", track=track, args=where)
+              if tr.enabled else NULL_SPAN):
+            for name, val in tile_reds.items():
+                spec = self.red_specs[name]
+                if name in self.reductions:
+                    self.reductions[name] = np.asarray(
+                        spec.combine(self.reductions[name], val))
+                else:
+                    self.reductions[name] = np.asarray(val)
 
     # -- edge carry -----------------------------------------------------------
     def copy_edges(self, op: CarryEdge, slot: Slot, dst: Slot,
@@ -929,7 +972,7 @@ class DataPlaneInterpreter(LedgerInterpreter):
                                      hi - next_org[name], td)].set(vals)
 
     # -- download -------------------------------------------------------------
-    def _make_download_task(self, arrays: Dict[str, Any],
+    def _make_download_task(self, tile: int, arrays: Dict[str, Any],
                             org: Dict[str, int],
                             items: List[Tuple[str, Interval]]
                             ) -> Callable[[], Tuple[int, int]]:
@@ -938,18 +981,28 @@ class DataPlaneInterpreter(LedgerInterpreter):
         codecs = self.codecs
         slot_slice = self._slot_slice
         write_np_region = self._write_np_region
+        tr = self.tracer
+        lane = self.down_track
+        stage_args = self._stage_args
 
         def task() -> Tuple[int, int]:
             raw = wire = 0
             for name, iv in items:
-                dat = info.datasets[name]
-                lo, hi = iv.lo - org[name], iv.hi - org[name]
-                arr = arrays[name]
-                vals = np.asarray(arr[slot_slice(arr, lo, hi, td)])
-                dec, r, w = codecs[name].roundtrip(vals)
-                raw += r
-                wire += w
-                write_np_region(dat, iv, np.asarray(dec, dat.dtype))
+                args = (stage_args(name, iv.lo, iv.hi, tile) if tr.enabled
+                        else None)
+                with (tr.span("stage_out", cat="stage", track=lane,
+                              args=args) if tr.enabled else NULL_SPAN):
+                    dat = info.datasets[name]
+                    lo, hi = iv.lo - org[name], iv.hi - org[name]
+                    arr = arrays[name]
+                    rows = arr[slot_slice(arr, lo, hi, td)]
+                    with (tr.span("d2h", cat="wait", track=lane, args=args)
+                          if tr.enabled else NULL_SPAN):
+                        vals = np.asarray(rows)
+                    dec, r, w = codecs[name].roundtrip(vals)
+                    raw += r
+                    wire += w
+                    write_np_region(dat, iv, np.asarray(dec, dat.dtype))
             return raw, wire
 
         return task
@@ -966,7 +1019,8 @@ class DataPlaneInterpreter(LedgerInterpreter):
             h for name, iv in items
             for h in self.rm.home_read_conflicts(name, iv.lo, iv.hi)]
         handle = self.tx.submit(
-            DOWN, self._make_download_task(dict(slot.arrays), org, items),
+            DOWN, self._make_download_task(op.tile, dict(slot.arrays), org,
+                                           items),
             deps=read_deps)
         self.down_handles[op.tile] = handle
         eid = self.ledger.add(2, "download", op.raw,
@@ -983,12 +1037,20 @@ class DataPlaneInterpreter(LedgerInterpreter):
         arr = self.pinned_arrays[name]
         origin = self.pinned_origins[name]
         raw_tot = wire_tot = 0
+        tr = self.tracer
+        lane = self.down_track
         for lo, hi in rows:
-            vals = np.asarray(arr[self._slot_slice(
-                arr, lo - origin, hi - origin, self.td)])
-            dec, r, w = self.codecs[name].roundtrip(vals)
-            raw_tot += r
-            wire_tot += w
-            self._write_np_region(dat, Interval(lo, hi),
-                                  np.asarray(dec, dat.dtype))
+            args = self._stage_args(name, lo, hi) if tr.enabled else None
+            with (tr.span("stage_out", cat="stage", track=lane, args=args)
+                  if tr.enabled else NULL_SPAN):
+                part = arr[self._slot_slice(arr, lo - origin, hi - origin,
+                                            self.td)]
+                with (tr.span("d2h", cat="wait", track=lane, args=args)
+                      if tr.enabled else NULL_SPAN):
+                    vals = np.asarray(part)
+                dec, r, w = self.codecs[name].roundtrip(vals)
+                raw_tot += r
+                wire_tot += w
+                self._write_np_region(dat, Interval(lo, hi),
+                                      np.asarray(dec, dat.dtype))
         return raw_tot, wire_tot

@@ -754,7 +754,6 @@ class ShardedOutOfCoreExecutor:
             prefetch_hits=sum(c.prefetch_hits for c in flat),
             wall_s=sum(c.wall_s for c in flat),
             modelled_s=modelled,
-            achieved_bw_model=loop_bytes / modelled if modelled else 0.0,
             slot_bytes=max((c.slot_bytes for c in flat), default=0),
             plan_cache_hit=all(c.plan_cache_hit for c in flat) if flat
             else False,

@@ -14,7 +14,9 @@ constraints, in order:
   dropped, never the run.  ``Tracer.dropped`` counts evictions.
 * **One clock.**  ``Tracer.clock`` is an injectable ``() -> float`` (default
   ``time.perf_counter``) so serve-layer stats and spans cannot disagree, and
-  tests can pin time.
+  tests can pin time.  :meth:`Tracer.anchor` marks one instant on both this
+  clock and a ``jax.profiler`` trace, so a reader can place the spans on the
+  profiler's timeline.
 """
 from __future__ import annotations
 
@@ -100,7 +102,9 @@ class _NullCtx:
         return None
 
 
-_NULL_CTX = _NullCtx()
+# Also for sites that build span args only when tracing:
+# ``with (tr.span(..., args={...}) if tr.enabled else NULL_SPAN):``.
+NULL_SPAN = _NullCtx()
 
 
 class Tracer:
@@ -132,6 +136,24 @@ class Tracer:
         """``with tracer.span("scatter", track="mesh"): ...`` — times the
         body on this tracer's clock and emits on exit."""
         return _SpanCtx(self, name, cat, track, args)
+
+    def anchor(self, name: str) -> Span:
+        """Mark one instant on this tracer's clock and on the profiler's.
+
+        Opens and closes ``jax.profiler.TraceAnnotation(name)`` (a host event
+        in any ``jax.profiler`` trace being taken) inside a span ``name``
+        (``cat="anchor"``, track ``anchor``) on this clock.  The offset
+        between the two events' midpoints places every span on the
+        profiler's timeline; two anchors, one after ``start_trace`` and one
+        before ``stop_trace``, bound the clocks' drift over the trace."""
+        import jax   # lazily: tracing stays importable without JAX
+
+        note = jax.profiler.TraceAnnotation(name)
+        t0 = self.clock()
+        with note:
+            pass
+        return self.emit(name, cat="anchor", track="anchor",
+                         t_start=t0, t_end=self.clock())
 
     def spans(self) -> List[Span]:
         """Snapshot of the ring, oldest first."""
@@ -175,7 +197,10 @@ class NullTracer:
 
     def span(self, name: str, *, cat: str = "", track: str = "",
              args: Optional[Dict[str, Any]] = None) -> _NullCtx:
-        return _NULL_CTX
+        return NULL_SPAN
+
+    def anchor(self, name: str) -> None:
+        return None
 
     def spans(self) -> List[Span]:
         return []

@@ -14,6 +14,7 @@ Two load-bearing properties:
 """
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -96,6 +97,7 @@ def test_null_tracer_fast_path_allocates_nothing():
     assert nt.span("a") is nt.span("b")
     assert nt.emit("x", t_start=0.0, t_end=1.0) is None
     assert nt.spans() == [] and len(nt) == 0
+    assert nt.anchor("x") is None
     # Shared instances pass through; fresh tracer on True; junk rejected.
     tr = Tracer()
     assert as_tracer(tr) is tr
@@ -103,6 +105,52 @@ def test_null_tracer_fast_path_allocates_nothing():
     assert isinstance(as_tracer(NullTracer()), NullTracer)
     with pytest.raises(TypeError):
         as_tracer("yes")
+
+
+class _RefusingNullTracer(NullTracer):
+    """A disabled tracer whose span/emit/anchor fail: a site that calls them
+    (and so builds their arguments) without checking ``enabled`` first."""
+
+    def span(self, *a, **kw):
+        raise AssertionError("span() called on a disabled tracer")
+
+    def emit(self, *a, **kw):
+        raise AssertionError("emit() called on a disabled tracer")
+
+    def anchor(self, *a, **kw):
+        raise AssertionError("anchor() called on a disabled tracer")
+
+
+def _clover_steps(sess, steps=2):
+    """CloverLeaf 2D's init, then ``steps`` timesteps with the dt read and
+    the field summary: plans, compiles, staging, pinned fields, prefetch
+    and reductions."""
+    app = CloverLeaf2D(40, 24, summary_every=0)
+    app.record_init(sess)
+    sess.flush()
+    sess.cyclic = True
+    for _ in range(steps):
+        app._ideal_gas(sess, "density0", "energy0", "_dt")
+        app._viscosity(sess)
+        app._calc_dt(sess)
+        app.dt = float(min(1e-4, sess.reduction("dt")))
+        app.record_timestep(sess)
+    for name in app.record_summary(sess):
+        sess.reduction(name)
+
+
+def test_new_span_sites_guard_on_enabled():
+    """Every span site checks ``tracer.enabled`` before building a span:
+    an untraced run never calls the tracer (so allocates nothing for it)."""
+    sess = Session("ooc", num_tiles=3, capacity_bytes=float("inf"),
+                   prefetch=True, pinned=("density0",),
+                   trace=_RefusingNullTracer())
+    try:
+        _clover_steps(sess)
+        assert sess.trace() is None
+        assert sum(h.uploaded for h in sess.history) > 0
+    finally:
+        sess.close()
 
 
 def test_untraced_session_exposes_no_trace():
@@ -279,6 +327,138 @@ def test_traced_chain_records_ledger_and_chain_spans():
     assert chain_spans[0].args["chain"] == 0
     assert len(sess.backend.ledgers) == 1
     sess.close()
+
+
+def _inside(inner, outer):
+    return outer.t_start <= inner.t_start and inner.t_end <= outer.t_end
+
+
+def _one_covering(span, candidates):
+    got = [c for c in candidates if c is not span and _inside(span, c)]
+    assert got, f"{span!r} lies in none of {len(candidates)} candidates"
+    return got
+
+
+def test_runtime_spans_nest_and_carry_chain_and_tile():
+    import jax
+
+    compiled = []
+
+    def on_compile(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(time.perf_counter())
+
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    tr = Tracer()
+    sess = Session("ooc", num_tiles=3, capacity_bytes=float("inf"),
+                   prefetch=True, pinned=("density0",), trace=tr)
+    try:
+        _clover_steps(sess, steps=4)
+        spans = tr.spans()
+        by = {}
+        for sp in spans:
+            by.setdefault(sp.name, []).append(sp)
+        for name, cat in [("plan", "plan"), ("tile_compile", "compile"),
+                          ("tile_dispatch", "dispatch"), ("stage_in", "stage"),
+                          ("stage_out", "stage"), ("d2h", "wait"),
+                          ("reduction_read", "wait"), ("upload_wait", "wait")]:
+            assert by.get(name), name
+            for sp in by[name]:
+                assert sp.cat == cat and isinstance(sp.args["chain"], int)
+        chains = {sp.args["chain"]: sp for sp in by["chain"]}
+        for sp in by["plan"]:
+            assert isinstance(sp.args["cache_hit"], bool)
+            assert sp.track == "chain"
+            assert _inside(sp, chains[sp.args["chain"]])
+        assert any(sp.args["cache_hit"] for sp in by["plan"])
+        tiles = {(sp.args["chain"], sp.args["tile"]): sp
+                 for sp in spans if sp.cat == "tile"}
+        for name in ("tile_dispatch", "upload_wait", "reduction_read"):
+            for sp in by[name]:
+                assert sp.track == "compute"
+                assert _inside(sp, tiles[sp.args["chain"], sp.args["tile"]])
+        # Each tile program compiles once, inside the launch that needed
+        # it, and that launch compiles nothing after it.
+        for sp in by["tile_compile"]:
+            launch, = _one_covering(sp, by["tile_dispatch"])
+            assert launch.args == {k: v for k, v in sp.args.items()
+                                   if k != "sig"}
+            assert not [t for t in compiled if sp.t_end < t <= launch.t_end]
+        engines = [cp.engine for cp in sess.backend._plans.values()]
+        assert len(by["tile_compile"]) == sum(len(e._cache) for e in engines)
+        for sp in by["d2h"]:
+            outer = _one_covering(sp, by["stage_out"])
+            assert outer[0].track == sp.track == "download"
+        for name, lane in (("stage_in", "upload"), ("stage_out", "download")):
+            for sp in by[name]:
+                assert sp.track == lane and sp.args["bytes"] > 0
+                assert sp.args["dat"] in sess.datasets
+        # The staging spans out count the chains' raw bytes down.
+        assert sum(sp.args["bytes"] for sp in by["stage_out"]) == sum(
+            h.downloaded for h in sess.history)
+        # The pinned field stages whole, outside any tile, both ways.
+        for name in ("stage_in", "stage_out"):
+            pinned = [sp for sp in by[name] if sp.args["dat"] == "density0"]
+            assert pinned and all("tile" not in sp.args for sp in pinned)
+        validate_chrome_trace(tr.chrome())
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+        sess.close()
+
+
+def test_anchors_put_spans_on_the_profilers_clock(tmp_path):
+    """On the CPU, under ``jax.profiler``: the two anchors give offsets
+    that agree within 100 us (beyond each anchor's own slack: the time its
+    span takes beyond its annotation's), and every chain span, moved by
+    the offset, lies inside the annotation around its flush."""
+    import glob
+    import os
+
+    import jax
+
+    tr = Tracer()
+    app = CloverLeaf2D(32, 24, summary_every=0)
+    sess = Session("ooc", num_tiles=2, capacity_bytes=float("inf"), trace=tr)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        first = tr.anchor("anchor.first")
+        app.record_init(sess)
+        with jax.profiler.TraceAnnotation("flush.init"):
+            sess.flush()
+        app.dt = 1e-4
+        app.record_timestep(sess)
+        with jax.profiler.TraceAnnotation("flush.step"):
+            sess.flush()
+        last = tr.anchor("anchor.last")
+    finally:
+        jax.profiler.stop_trace()
+        sess.close()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    notes = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(("anchor.", "flush.")):
+                    notes[ev.name] = (ev.start_ns, ev.start_ns + ev.duration_ns)
+
+    def offset_and_slack(span):
+        lo, hi = notes[span.name]
+        slack = (span.duration * 1e9 - (hi - lo)) / 2
+        return (lo + hi) / 2 - (span.t_start + span.t_end) / 2 * 1e9, slack
+
+    (o1, s1), (o2, s2) = offset_and_slack(first), offset_and_slack(last)
+    assert abs(o2 - o1) <= 100e3 + s1 + s2
+    offset = (o1 + o2) / 2
+    flushes = [notes["flush.init"], notes["flush.step"]]
+    chains = [sp for sp in tr.spans() if sp.cat == "chain"]
+    assert len(chains) == 2
+    for sp, (lo, hi) in zip(chains, flushes):
+        a, b = sp.t_start * 1e9 + offset, sp.t_end * 1e9 + offset
+        assert lo - 100e3 - s1 - s2 <= a and b <= hi + 100e3 + s1 + s2
 
 
 # -- bit-identity: tracing observes, never perturbs ---------------------------------
