@@ -185,12 +185,17 @@ class CloverLeaf2D:
             k, reductions=[ReductionSpec("dt", "min")],
         )
 
+    def _dt_gbl(self, scale: float = 1.0) -> Dict[str, object]:
+        """The step's dt as the gbl its loops declare, at the fields' dtype:
+        passed as data, a new dt replays the cached plan and programs."""
+        return {"dt": np.dtype(self.dtype).type(self.dt * scale)}
+
     def _pdv(self, rt, predict: bool, tag: str):
-        dt = self.dt * (0.5 if predict else 1.0)
         dst_rho = "density1"
         dst_e = "energy1"
 
         def k(acc):
+            dt = acc.gbl("dt")
             div = (acc("xvel0", (1, 0)) - acc("xvel0")) + (acc("yvel0", (0, 1)) - acc("yvel0"))
             vol_change = 1.0 + dt * div
             rho = acc("density0") / jnp.maximum(vol_change, 0.1)
@@ -202,7 +207,7 @@ class CloverLeaf2D:
             [self.d("xvel0"), self.d("yvel0"), self.d("density0"),
              self.d("energy0"), self.d("pressure"), self.d(dst_rho),
              self.d(dst_e)],
-            k,
+            k, gbls=self._dt_gbl(0.5 if predict else 1.0),
         )
 
     def _revert(self, rt):
@@ -217,10 +222,10 @@ class CloverLeaf2D:
         )
 
     def _accelerate(self, rt):
-        dt = self.dt
         rng = ((1, self.nx), (1, self.ny))
 
         def k(acc):
+            dt = acc.gbl("dt")
             # node-centred density from 4 surrounding cells
             nodal_mass = 0.25 * (acc("density0") + acc("density0", (-1, 0))
                                  + acc("density0", (0, -1)) + acc("density0", (-1, -1)))
@@ -237,13 +242,12 @@ class CloverLeaf2D:
             [self.d("density0"), self.d("pressure"), self.d("viscosity"),
              self.d("xvel0"), self.d("yvel0"), self.d("xvel1"),
              self.d("yvel1")],
-            k,
+            k, gbls=self._dt_gbl(),
         )
 
     def _flux_calc(self, rt):
-        dt = self.dt
-
         def k(acc):
+            dt = acc.gbl("dt")
             fx = 0.5 * dt * (acc("xvel1") + acc("xvel1", (0, 1))) * acc("xarea")
             fy = 0.5 * dt * (acc("yvel1") + acc("yvel1", (1, 0))) * acc("yarea")
             return {"vol_flux_x": fx, "vol_flux_y": fy}
@@ -252,7 +256,7 @@ class CloverLeaf2D:
             "flux_calc", self.block, self._interior(),
             [self.d("xvel1"), self.d("yvel1"), self.d("xarea"),
              self.d("yarea"), self.d("vol_flux_x"), self.d("vol_flux_y")],
-            k,
+            k, gbls=self._dt_gbl(),
         )
 
     def _advec_cell(self, rt, sweep: str):

@@ -172,10 +172,14 @@ class CloverLeaf3D:
             k, reductions=[ReductionSpec("dt", "min")],
         )
 
-    def _pdv(self, rt, predict, tag):
-        dt = self.dt * (0.5 if predict else 1.0)
+    def _dt_gbl(self, scale: float = 1.0) -> Dict[str, object]:
+        """The step's dt as the gbl its loops declare, at the fields' dtype:
+        passed as data, a new dt replays the cached plan and programs."""
+        return {"dt": np.dtype(self.dtype).type(self.dt * scale)}
 
+    def _pdv(self, rt, predict, tag):
         def k(acc):
+            dt = acc.gbl("dt")
             div = ((acc("xvel0", (1, 0, 0)) - acc("xvel0"))
                    + (acc("yvel0", (0, 1, 0)) - acc("yvel0"))
                    + (acc("zvel0", (0, 0, 1)) - acc("zvel0")))
@@ -188,7 +192,7 @@ class CloverLeaf3D:
             [self.d("xvel0"), self.d("yvel0"), self.d("zvel0"),
              self.d("density0"), self.d("energy0"), self.d("pressure"),
              self.d("density1"), self.d("energy1")],
-            k,
+            k, gbls=self._dt_gbl(0.5 if predict else 1.0),
         )
 
     def _revert(self, rt):
@@ -203,10 +207,10 @@ class CloverLeaf3D:
         )
 
     def _accelerate(self, rt):
-        dt = self.dt
         rng = ((1, self.nx), (1, self.ny), (1, self.nz))
 
         def k(acc):
+            dt = acc.gbl("dt")
             nodal = 0.125 * sum(
                 acc("density0", o) for o in self.S_node.points
             )
@@ -222,13 +226,12 @@ class CloverLeaf3D:
             [self.d("density0"), self.d("pressure"), self.d("viscosity")]
             + [self.d(f"{v}0") for v in ("xvel", "yvel", "zvel")]
             + [self.d(f"{v}1") for v in ("xvel", "yvel", "zvel")],
-            k,
+            k, gbls=self._dt_gbl(),
         )
 
     def _flux_calc(self, rt):
-        dt = self.dt
-
         def k(acc):
+            dt = acc.gbl("dt")
             return {
                 "vol_flux_x": 0.5 * dt * (acc("xvel1") + acc("xvel1", (0, 1, 0))) * acc("xarea"),
                 "vol_flux_y": 0.5 * dt * (acc("yvel1") + acc("yvel1", (0, 0, 1))) * acc("yarea"),
@@ -240,7 +243,7 @@ class CloverLeaf3D:
             [self.d("xvel1"), self.d("yvel1"), self.d("zvel1")]
             + [self.d(a) for a in ("xarea", "yarea", "zarea")]
             + [self.d(f) for f in ("vol_flux_x", "vol_flux_y", "vol_flux_z")],
-            k,
+            k, gbls=self._dt_gbl(),
         )
 
     def _advec_cell(self, rt, sweep):

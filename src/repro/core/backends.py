@@ -100,7 +100,8 @@ class PallasBackend:
 
     Loops whose kernel carries a ``pallas_op`` tag (built by
     :func:`repro.kernels.star2d_kernel` / ``star3d_kernel``) execute through
-    the Pallas TPU kernels (``stencil2d``/``stencil3d``); untagged loops fall
+    the Pallas TPU kernels (``stencil2d``/``stencil3d``); untagged loops, and
+    loops that declare gbls (the tag's coefficients are constants), fall
     back to the reference path, so arbitrary chains still run correctly.
     ``fallback_loops`` counts those.  ``interpret`` is passed to the kernels
     (None: compiled on a TPU, interpreted on the CPU).
@@ -116,7 +117,7 @@ class PallasBackend:
         merged: Dict[str, np.ndarray] = {}
         for lp in loops:
             op = getattr(lp.kernel, "pallas_op", None)
-            if op is not None and self._try_pallas(lp, op):
+            if op is not None and not lp.gbls and self._try_pallas(lp, op):
                 self.pallas_loops += 1
                 continue
             self.fallback_loops += 1

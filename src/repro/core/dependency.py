@@ -24,7 +24,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Sequence, Set, Tuple
 
 from .dataset import Dataset
-from .loop import AccessMode, ParallelLoop
+from .loop import AccessMode, ParallelLoop, gbl_signature
 
 
 def _merge(intervals: List[Tuple[int, int]]) -> List[Tuple[int, int]]:
@@ -205,6 +205,9 @@ def chain_signature(info: ChainInfo) -> Tuple:
 # object plus captured/default values so a changed constant forces a re-plan;
 # captured values that aren't plain data (datasets, app objects) hash by type —
 # the documented kernel contract is that such captures are static config.
+# A loop's declared gbls are data, not code: the keys hold their names and
+# dtypes, and the tile programs take their values as arguments, so a new dt
+# passed that way replays the cached plan and its compiled programs.
 
 _PRIMITIVES = (bool, int, float, str, bytes, type(None))
 
@@ -300,8 +303,9 @@ def loop_kernel_fingerprint(lp: ParallelLoop) -> Tuple:
 
 def plan_signature(loops: Sequence[ParallelLoop], tiled_dim: int = 0) -> Tuple:
     """Replay-safe fingerprint of a chain: structure + dataset identity +
-    kernel fingerprints.  Two chains with equal plan signatures execute
-    identically through a cached plan (analysis, schedule, compiled tiles)."""
+    kernel fingerprints + gbl names and dtypes.  Two chains with equal plan
+    signatures execute identically through a cached plan (analysis,
+    schedule, compiled tiles), given each chain's own gbl values."""
     return (tiled_dim,) + tuple(
         (
             lp.name,
@@ -310,6 +314,7 @@ def plan_signature(loops: Sequence[ParallelLoop], tiled_dim: int = 0) -> Tuple:
                   for a in lp.args),
             tuple((r.name, r.op) for r in lp.reductions),
             loop_kernel_fingerprint(lp),
+            gbl_signature(lp.gbls),
         )
         for lp in loops
     )
@@ -341,6 +346,7 @@ def shared_plan_signature(loops: Sequence[ParallelLoop], tiled_dim: int = 0) -> 
                   for a in lp.args),
             tuple((r.name, r.op) for r in lp.reductions),
             loop_kernel_fingerprint(lp),
+            gbl_signature(lp.gbls),
         )
         for lp in loops
     )

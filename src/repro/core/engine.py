@@ -4,7 +4,10 @@ Loops execute under one ``jax.jit`` per *tile signature* (the pattern of
 active loops and their static box sizes).  Interior tiles share a signature,
 so a chain compiles O(3) times regardless of tile count: tiled-dim starts and
 slot origins enter as traced int32 scalars and all slices are
-``lax.dynamic_slice`` / ``lax.dynamic_update_slice``.
+``lax.dynamic_slice`` / ``lax.dynamic_update_slice``.  A loop's declared
+gbls enter beside its start, as traced 0-d arrays, so a new value
+(CloverLeaf's dt every step) reuses the compiled program; a loop without
+gbls adds no argument, so its programs are those of a chain without them.
 
 This is the moral equivalent of Algorithm 1 line 8 ("adjust base pointers of
 datasets for virtual position"): the kernel addresses global grid
@@ -13,7 +16,7 @@ coordinates; the engine rebases them into slot-local offsets.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,7 +24,7 @@ import numpy as np
 from jax import lax
 
 from .dependency import ChainInfo
-from .loop import AccessMode, Accessor, ParallelLoop
+from .loop import AccessMode, Accessor, ParallelLoop, read_gbl
 from .tiling import TilePlan, TileSchedule
 from ..obs.tracer import AnyTracer, NULL_TRACER
 
@@ -29,7 +32,8 @@ from ..obs.tracer import AnyTracer, NULL_TRACER
 class _SliceAccessor(Accessor):
     """Accessor over slot arrays for one loop's iteration box."""
 
-    def __init__(self, loop, box_sizes, td, start_td, origins, slots, halos):
+    def __init__(self, loop, box_sizes, td, start_td, origins, slots, halos,
+                 gbls):
         self._loop = loop
         self._sizes = box_sizes
         self.shape = tuple(box_sizes)
@@ -38,7 +42,11 @@ class _SliceAccessor(Accessor):
         self._origins = origins            # traced: per-dat slot origin
         self._slots = slots
         self._halos = halos                # per-dat halo_lo tuple
+        self._gbls = gbls                  # traced: the loop's gbls
         self._args = {a.dat.name: a for a in loop.args}
+
+    def gbl(self, name: str):
+        return read_gbl(self._gbls, name, self._loop.name)
 
     def coords(self):
         """Global grid coordinates over the box, broadcast to full box shape."""
@@ -94,14 +102,16 @@ class TileEngine:
     def _build(self, sig: Tuple):
         chain, td, halos = self.chain, self.td, self.halos
 
-        def tile_fn(slots, starts, origins):
+        def tile_fn(slots, loop_args, origins):
             reds = {}
             slots = dict(slots)
             for k, lp in enumerate(chain.loops):
                 sizes = sig[k]
                 if sizes is None:
                     continue
-                acc = _SliceAccessor(lp, sizes, td, starts[k], origins, slots, halos)
+                start, gbls = loop_args[k]
+                acc = _SliceAccessor(lp, sizes, td, start, origins, slots,
+                                     halos, gbls)
                 out = lp.kernel(acc)
                 if not isinstance(out, dict):
                     raise TypeError(f"kernel of {lp.name!r} must return a dict")
@@ -120,7 +130,7 @@ class TileEngine:
                     idx = []
                     for d in range(lp.block.ndim):
                         if d == td:
-                            idx.append(starts[k] - origins[name])
+                            idx.append(start - origins[name])
                         else:
                             idx.append(lp.range_[d][0] + halos[name][d])
                     if arg.mode is AccessMode.INC:
@@ -143,8 +153,10 @@ class TileEngine:
         return jax.jit(tile_fn)
 
     def program(self, tile: TilePlan):
-        """The jitted tile function for ``tile``'s signature, called as
-        ``fn(slots, starts, origins)`` (built on first use, then cached)."""
+        """The jitted tile function for ``tile``'s signature (built on first
+        use, then cached), called as ``fn(slots, loop_args, origins)``:
+        ``loop_args[k]`` is active loop ``k``'s ``(start, gbls)``, its box's
+        start along the tiled dim and its gbl values."""
         sig = self._signature(tile)
         fn = self._cache.get(sig)
         if fn is None:
@@ -152,24 +164,38 @@ class TileEngine:
             self._cache[sig] = fn
         return fn
 
+    @staticmethod
+    def tile_gbls(tile: TilePlan, loop_gbls: Sequence[Mapping[str, Any]]
+                  ) -> Dict[int, Mapping[str, Any]]:
+        """The gbl values of ``tile``'s active loops, by loop index, from
+        ``loop_gbls`` (one mapping per loop of the chain being run).  Loops
+        that declare none are left out, so a chain without gbls passes an
+        empty dict and its programs are those of a chain without them."""
+        return {k: loop_gbls[k] for k, box in enumerate(tile.loop_ranges)
+                if box is not None and loop_gbls[k]}
+
     def run_tile(
         self,
         tile: TilePlan,
         slots: Dict[str, jax.Array],
         origins: Dict[str, int],
+        gbls: Dict[int, Mapping[str, Any]],
         *,
         tracer: AnyTracer = NULL_TRACER,
         track: str = "compute",
         args: Optional[Dict] = None,
     ) -> Tuple[Dict[str, jax.Array], Dict[str, jax.Array]]:
-        """Launch ``tile``'s program.  Traced, a signature seen for the first
-        time is lowered and compiled inside a ``tile_compile`` span (``args``
-        plus ``sig``, the signature's index in this engine) before the call,
-        which then finds it compiled."""
+        """Launch ``tile``'s program.  ``gbls`` are the gbl values of the
+        tile's active loops (:meth:`tile_gbls`), taken from the chain being
+        run: a cached plan's ``chain.loops`` belong to the chain that first
+        built it.  Traced, a signature seen for the first time is lowered
+        and compiled inside a ``tile_compile`` span (``args`` plus ``sig``,
+        the signature's index in this engine) before the call, which then
+        finds it compiled."""
         n_programs = len(self._cache)
         fn = self.program(tile)
-        starts = {
-            k: jnp.int32(box[self.td][0])
+        loop_args = {
+            k: (jnp.int32(box[self.td][0]), gbls.get(k, {}))
             for k, box in enumerate(tile.loop_ranges)
             if box is not None
         }
@@ -177,5 +203,5 @@ class TileEngine:
         if tracer.enabled and len(self._cache) > n_programs:
             with tracer.span("tile_compile", cat="compile", track=track,
                              args={**(args or {}), "sig": n_programs}):
-                fn.lower(slots, starts, origins_t).compile()
-        return fn(slots, starts, origins_t)
+                fn.lower(slots, loop_args, origins_t).compile()
+        return fn(slots, loop_args, origins_t)

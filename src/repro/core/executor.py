@@ -170,10 +170,10 @@ class OutOfCoreExecutor:
         # The JAX device this executor stages onto and computes on (a
         # sharded mesh gives each shard its own); None: JAX's default.
         self.device = device
-        # LRU-bounded: kernels capturing a per-step constant (a real dt
-        # changing every step) legitimately produce a new plan per flush —
-        # without a bound a long run would accumulate engines/ChainInfos
-        # (and their jit caches) without limit.
+        # LRU-bounded: kernels capturing a per-step constant (a dt captured
+        # rather than passed as a gbl) legitimately produce a new plan per
+        # flush — without a bound a long run would accumulate
+        # engines/ChainInfos (and their jit caches) without limit.
         self._plans: "OrderedDict[Tuple, ChainPlan]" = OrderedDict()
         self._max_plans = 32
         self._no_fit: set = set()   # keys known to raise MemoryError
@@ -484,7 +484,8 @@ class OutOfCoreExecutor:
                 codecs=resolve_codecs(cfg.codec, tuple(cp.info.datasets)),
                 halo_runtime=self.halo_runtime,
                 tracer=tr, trace_tag=self.trace_tag,
-                chain_index=chain_index, device=self.device)
+                chain_index=chain_index, device=self.device,
+                gbls=[lp.gbls for lp in loops])
         res = interp.run()
         if tr.enabled:
             self.ledgers.append(res.ledger)

@@ -584,7 +584,9 @@ class DataPlaneInterpreter(LedgerInterpreter):
     and patched with achieved post-codec wire bytes after the engine drains.
     ``device`` places every array the chain stages (slots, pinned arrays,
     prefetch captures) and so the compiled tiles that consume them; None
-    keeps JAX's default device.
+    keeps JAX's default device.  ``gbls`` holds one mapping per loop of the
+    chain being run: the values its tiles take, which a cached ``cp`` (built
+    by an earlier chain with other values) does not hold.
     """
 
     # Wall-clock spans (dispatch + lane); the ledger keeps the model.
@@ -598,7 +600,8 @@ class DataPlaneInterpreter(LedgerInterpreter):
                  tracer: Optional[AnyTracer] = None,
                  trace_tag: str = "",
                  chain_index: int = 0,
-                 device: Optional[Any] = None):
+                 device: Optional[Any] = None,
+                 gbls: Sequence[Dict[str, Any]]):
         super().__init__(plan, hw, rm=rm, spec=spec,
                          datasets=cp.info.datasets,
                          tracer=tracer, trace_tag=trace_tag,
@@ -613,6 +616,7 @@ class DataPlaneInterpreter(LedgerInterpreter):
         self.info = cp.info
         self.sched = cp.sched
         self.engine = cp.engine
+        self.gbls = gbls
         self.tx = tx
         self.codecs = codecs
         self.td = plan.tiled_dim
@@ -934,11 +938,14 @@ class DataPlaneInterpreter(LedgerInterpreter):
         tile = self.sched.tiles[op.tile]
         run_arrays = {**slot.arrays, **self.pinned_arrays}
         run_origins = {**self.origins[op.tile], **self.pinned_origins}
+        gbls = self.engine.tile_gbls(tile, self.gbls)
+        launch = ({**where, "gbls": sum(map(len, gbls.values()))}
+                  if tr.enabled else None)
         with (tr.span("tile_dispatch", cat="dispatch", track=track,
-                      args=where) if tr.enabled else NULL_SPAN):
+                      args=launch) if tr.enabled else NULL_SPAN):
             new_arrays, tile_reds = self.engine.run_tile(
-                tile, run_arrays, run_origins, tracer=tr, track=track,
-                args=where)
+                tile, run_arrays, run_origins, gbls, tracer=tr, track=track,
+                args=launch)
         for name in self.pinned_arrays:
             self.pinned_arrays[name] = new_arrays[name]
             self.rm.pinned_update(self.info.datasets[name], new_arrays[name])

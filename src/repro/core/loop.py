@@ -10,12 +10,18 @@ kernels are pure array functions of their stencil reads.
 
 Write/RW/INC arguments must use the zero stencil (same restriction as OPS);
 READ arguments may use any stencil.
+
+A loop may also declare named read-only scalars, ``gbls`` (OPS's
+``ops_arg_gbl(&dt, 1, "double", OPS_READ)``): values that go into the kernel
+as data, read through ``acc.gbl(name)``, not as constants of its code.
 """
 from __future__ import annotations
 
 import enum
 from dataclasses import dataclass, field
-from typing import Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Mapping, Optional, Sequence, Tuple
+
+import numpy as np
 
 from .block import Block
 from .dataset import Dataset
@@ -83,6 +89,37 @@ class Accessor:
     def coords(self):  # pragma: no cover
         raise NotImplementedError
 
+    def gbl(self, name: str):  # pragma: no cover
+        """The read-only scalar ``name`` the loop declared with
+        ``par_loop(..., gbls={name: value})``, as a 0-d value of the dtype
+        it was given in.  A value that changes between steps (a CFL ``dt``)
+        belongs here: compiled tile programs and cached plans take it as an
+        argument, while a constant the kernel captures is part of its code
+        and re-plans whenever it changes.  A name the loop did not declare
+        is a ``KeyError``."""
+        raise NotImplementedError
+
+
+def read_gbl(gbls: Mapping[str, Any], name: str, loop: str):
+    """``gbls[name]``, or the ``KeyError`` an undeclared scalar gets."""
+    try:
+        return gbls[name]
+    except KeyError:
+        raise KeyError(
+            f"loop {loop!r} reads gbl {name!r} which it did not declare "
+            f"(gbls: {sorted(gbls)})") from None
+
+
+def gbl_signature(gbls: Mapping[str, Any]) -> Tuple[Tuple[str, str], ...]:
+    """Names and dtypes of a loop's gbls, never their values: what the plan
+    caches key on."""
+    return tuple(sorted((n, _dtype_of(v).str) for n, v in gbls.items()))
+
+
+def _dtype_of(value) -> np.dtype:
+    dtype = getattr(value, "dtype", None)     # NumPy and JAX scalars
+    return np.dtype(dtype) if dtype is not None else np.asarray(value).dtype
+
 
 Kernel = Callable[[Accessor], Dict[str, "jax.Array"]]  # noqa: F821
 
@@ -121,10 +158,17 @@ class ParallelLoop:
     args: Tuple[Arg, ...]
     kernel: Kernel
     reductions: Tuple[ReductionSpec, ...] = ()
+    gbls: Mapping[str, Any] = field(default_factory=dict)
 
     def __post_init__(self) -> None:
         if len(self.range_) != self.block.ndim:
             raise ValueError(f"loop {self.name!r}: range arity mismatch")
+        self.gbls = dict(self.gbls)
+        for gname, value in self.gbls.items():
+            if not isinstance(gname, str) or np.ndim(value) != 0:
+                raise ValueError(
+                    f"loop {self.name!r}: gbl {gname!r} must be a named "
+                    f"scalar (got shape {np.shape(value)})")
         for a, b in self.range_:
             if b < a:
                 raise ValueError(f"loop {self.name!r}: empty/negative range {self.range_}")

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass, replace
-from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Set, Tuple, Union
 
 import numpy as np
 
@@ -150,10 +150,11 @@ class _TracingAccessor(Accessor):
     """
 
     def __init__(self, block: Block, range_: Tuple[Tuple[int, int], ...],
-                 dats: Dict[str, Dataset]):
+                 dats: Dict[str, Dataset], gbls: Mapping[str, Any]):
         self._block = block
         self._range = range_
         self._dats = dats
+        self._gbls = gbls
         self.shape = tuple(min(b - a, 3) for a, b in range_)
         self.reads: Dict[str, Set[Tuple[int, ...]]] = {}
 
@@ -167,6 +168,13 @@ class _TracingAccessor(Accessor):
             shape[d] = self.shape[d]
             out.append(np.broadcast_to(ar.reshape(shape), self.shape))
         return tuple(out)
+
+    def gbl(self, name: str):
+        if name not in self._gbls:
+            raise KeyError(
+                f"kernel reads gbl {name!r} which was not passed to "
+                f"par_loop (gbls: {sorted(self._gbls)})")
+        return self._gbls[name]
 
     def __call__(self, name: str, offset: Tuple[int, ...] = None):
         if name not in self._dats:
@@ -199,9 +207,11 @@ def trace_kernel(
     range_: Tuple[Tuple[int, int], ...],
     dats: Dict[str, Dataset],
     reductions: Sequence[ReductionSpec] = (),
+    gbls: Optional[Mapping[str, Any]] = None,
 ) -> KernelTrace:
-    """Run ``kernel`` once against abstract data and classify its accesses."""
-    acc = _TracingAccessor(block, range_, dats)
+    """Run ``kernel`` once against abstract data and classify its accesses;
+    ``acc.gbl`` reads ``gbls``."""
+    acc = _TracingAccessor(block, range_, dats, gbls or {})
     out = kernel(acc)
     if not isinstance(out, dict):
         raise TypeError(
@@ -236,6 +246,7 @@ def infer_args(
     inc: Sequence[str] = (),
     explicit_stencil: Optional[Dict[str, Stencil]] = None,
     extra: Sequence[Arg] = (),
+    gbls: Optional[Mapping[str, Any]] = None,
 ) -> Tuple[Arg, ...]:
     """Build the ``Arg`` list for ``dats`` from a kernel trace.
 
@@ -248,7 +259,7 @@ def infer_args(
     by_name = {d.name: d for d in dats}
     for a in extra:
         by_name.setdefault(a.dat.name, a.dat)
-    trace = trace_kernel(kernel, block, range_, by_name, reductions)
+    trace = trace_kernel(kernel, block, range_, by_name, reductions, gbls)
     nd = block.ndim
     zero = point_stencil(nd)
     written = set(trace.writes)
@@ -315,6 +326,7 @@ def validate_declared_args(
     reductions: Sequence[ReductionSpec] = (),
     loop_name: str = "?",
     extra_dats: Sequence[Dataset] = (),
+    gbls: Optional[Mapping[str, Any]] = None,
 ) -> None:
     """Check hand-declared ``Arg`` lists against the kernel trace.
 
@@ -329,7 +341,7 @@ def validate_declared_args(
     declared_names = set(by_name)
     for d in extra_dats:
         by_name.setdefault(d.name, d)
-    trace = trace_kernel(kernel, block, range_, by_name, reductions)
+    trace = trace_kernel(kernel, block, range_, by_name, reductions, gbls)
     problems: List[str] = []
     declared_reads: Dict[str, Set[Tuple[int, ...]]] = {}
     declared_writes: Set[str] = set()
@@ -419,6 +431,7 @@ class Session:
         *,
         inc: Sequence[str] = (),
         explicit_stencil: Optional[Dict[str, Stencil]] = None,
+        gbls: Optional[Mapping[str, Any]] = None,
     ) -> None:
         """Record one parallel loop.
 
@@ -427,6 +440,15 @@ class Session:
         or fully-explicit :class:`Arg` declarations (the two styles mix).
         ``explicit_stencil={name: stencil}`` overrides the inferred READ
         stencil for that dataset; ``inc=[name]`` marks accumulating writes.
+
+        ``gbls={name: value}`` declares read-only scalars the kernel reads
+        as ``acc.gbl(name)`` (OPS's ``ops_arg_gbl``).  They go into compiled
+        tile programs as arguments, so a value that changes between steps —
+        a CFL ``dt`` — belongs here: the cached plan and its programs serve
+        every value.  A value the kernel captures in its closure instead is
+        part of its code, and every new value re-plans and recompiles.
+        Give each value at the dtype the computation should use
+        (``np.float32(dt)``); reading an undeclared name is a ``KeyError``.
         """
         if self._closed:
             raise SessionClosedError(
@@ -444,18 +466,19 @@ class Session:
                     f"loop {name!r}: args entries must be Arg or Dataset, "
                     f"got {type(a).__name__}")
         validate = self.config is not None and self.config.validate_stencils
+        gbls = gbls or {}
         kernel_fp = None
         if inferred_dats:
             kernel_fp = kernel_fingerprint(kernel)
             inferred = self._infer_cached(
                 kernel_fp, block, range_t, inferred_dats, kernel,
                 tuple(reductions), tuple(inc), explicit_stencil,
-                tuple(declared))
+                tuple(declared), gbls)
             all_args = tuple(declared) + inferred
             if validate and declared:
                 validate_declared_args(
                     kernel, block, range_t, declared, reductions, name,
-                    extra_dats=inferred_dats)
+                    extra_dats=inferred_dats, gbls=gbls)
         else:
             # inc/explicit_stencil only shape *inference* — with an all-Arg
             # loop they would be silently dropped, so reject them loudly.
@@ -466,10 +489,11 @@ class Session:
             all_args = tuple(declared)
             if validate:
                 validate_declared_args(
-                    kernel, block, range_t, declared, reductions, name)
+                    kernel, block, range_t, declared, reductions, name,
+                    gbls=gbls)
         lp = ParallelLoop(
             name=name, block=block, range_=range_t, args=all_args,
-            kernel=kernel, reductions=tuple(reductions),
+            kernel=kernel, reductions=tuple(reductions), gbls=gbls,
         )
         for a in all_args:
             self.datasets[a.dat.name] = a.dat
@@ -478,7 +502,7 @@ class Session:
         self.queue.append(lp)
 
     def _infer_cached(self, kernel_fp, block, range_t, dats, kernel,
-                      reductions, inc, explicit_stencil, declared
+                      reductions, inc, explicit_stencil, declared, gbls
                       ) -> Tuple[Arg, ...]:
         key = (
             kernel_fp,
@@ -488,12 +512,13 @@ class Session:
             tuple((r.name, r.op) for r in reductions),
             inc,
             tuple(sorted((n, s.points) for n, s in (explicit_stencil or {}).items())),
+            tuple(sorted(gbls)),
         )
         cached = self._arg_cache.get(key)
         if cached is None:
             cached = infer_args(
                 kernel, block, range_t, dats, reductions, inc,
-                explicit_stencil, extra=declared)
+                explicit_stencil, extra=declared, gbls=gbls)
             self._arg_cache[key] = cached
             if len(self._arg_cache) > self._max_arg_cache:
                 self._arg_cache.popitem(last=False)

@@ -10,7 +10,7 @@ from typing import Dict, Sequence, Tuple
 
 import numpy as np
 
-from .loop import AccessMode, Accessor, ParallelLoop
+from .loop import AccessMode, Accessor, ParallelLoop, read_gbl
 
 
 class _NumpyAccessor(Accessor):
@@ -29,6 +29,9 @@ class _NumpyAccessor(Accessor):
             shape[d] = ar.size
             out.append(np.broadcast_to(ar.reshape(shape), self.shape))
         return tuple(out)
+
+    def gbl(self, name: str):
+        return read_gbl(self._loop.gbls, name, self._loop.name)
 
     def __call__(self, name: str, offset: Tuple[int, ...] = None):
         lp = self._loop

@@ -132,6 +132,9 @@ class _OffsetAccessor(Accessor):
     def __call__(self, name, offset=None):
         return self._inner(name, offset)
 
+    def gbl(self, name):
+        return self._inner.gbl(name)
+
 
 def shift_kernel(kernel, offsets: Tuple[int, ...]):
     """Wrap ``kernel`` so its accessor reports global coordinates."""
@@ -403,7 +406,8 @@ class ShardedOutOfCoreExecutor:
                              for d in range(lp.block.ndim)))
         local = ParallelLoop(
             name=lp.name, block=state.blocks[s], range_=tuple(range_),
-            args=args, kernel=kernel, reductions=lp.reductions)
+            args=args, kernel=kernel, reductions=lp.reductions,
+            gbls=lp.gbls)
         # Plan-cache key stability: derive the local kernel fingerprint from
         # the (memoised) global one instead of re-walking the wrapper.
         local.__dict__["_kernel_fp"] = (

@@ -4,7 +4,8 @@ import pytest
 
 from repro.apps import CloverLeaf2D, CloverLeaf3D, OpenSBLI
 from repro.core import (
-    OOCConfig, OutOfCoreExecutor, ReferenceRuntime, Runtime, analyze_chain,
+    Accessor, OOCConfig, OutOfCoreExecutor, ReferenceRuntime, Runtime, Session,
+    analyze_chain,
 )
 
 
@@ -70,6 +71,87 @@ class TestCloverLeaf2D:
         # the §4.1 temporaries exist and are write-first
         for tmp in ("pre_vol", "post_vol", "pre_mass", "ener_flux"):
             assert tmp in info.write_first
+
+
+class _Constants(Accessor):
+    """``acc`` with ``gbl`` answering from constants of the kernel's
+    closure."""
+
+    def __init__(self, acc, consts):
+        self._acc, self._consts = acc, consts
+        self.shape = acc.shape
+
+    def coords(self):
+        return self._acc.coords()
+
+    def __call__(self, name, offset=None):
+        return self._acc(name, offset)
+
+    def gbl(self, name):
+        return self._consts[name]
+
+
+class _CapturedGblsSession(Session):
+    """The oracle: each loop's gbls captured as Python floats in its
+    kernel's closure, as CloverLeaf captured dt before passing it as data,
+    so every new dt re-plans and recompiles."""
+
+    def par_loop(self, name, block, range_, args, kernel, reductions=(), *,
+                 gbls=None, **kw):
+        if gbls:
+            consts = {n: float(v) for n, v in gbls.items()}
+            inner = kernel
+
+            def kernel(acc):
+                return inner(_Constants(acc, consts))
+        super().par_loop(name, block, range_, args, kernel, reductions, **kw)
+
+
+def _clover_window(sess_type, trace):
+    """CloverLeaf 2D out of core at a third of its working set, 6 timesteps
+    driven as the chip benchmark drives them, each with its own dt below
+    the cap.  800 rows: the timestep chain fits unsplit, as it does at
+    3840^2.  The tracer is cleared once the first two timesteps (the two
+    sweep orders) have run, so it holds the rest."""
+    app = CloverLeaf2D(800, 8, summary_every=0)
+    sess = sess_type("ooc", capacity_bytes=app.total_bytes() / 3,
+                     prefetch=True, trace=trace)
+    app.record_init(sess)
+    sess.flush()
+    sess.cyclic = True
+    for step in range(7):
+        app._ideal_gas(sess, "density0", "energy0", "_dt")
+        app._viscosity(sess)
+        app._calc_dt(sess)
+        sess.reduction("dt")
+        if step == 2 and trace:
+            sess.trace().clear()
+        if step == 6:
+            break
+        app.dt = (0.5 + 0.05 * step) * 1e-4
+        app.record_timestep(sess)
+    fields = {n: app.d(n).interior().copy()
+              for n in ("density0", "energy0", "xvel0", "yvel0")}
+    spans = sess.trace().spans() if trace else []
+    sess.close()
+    return fields, spans
+
+
+def test_cloverleaf2d_new_dt_compiles_nothing():
+    """dt passed as a gbl: after the two sweep orders have compiled, every
+    later step replays its cached plan and compiled tile programs, and the
+    fields are those of the same run with dt captured as a constant."""
+    fields, spans = _clover_window(Session, trace=True)
+    plans = [sp for sp in spans if sp.name == "plan"]
+    assert len(plans) == 4
+    assert all(sp.args["cache_hit"] for sp in plans)
+    assert not [sp for sp in spans if sp.name == "tile_compile"]
+    # Each launch passes the dt of both PdVs, accelerate and flux_calc.
+    assert {sp.args["gbls"] for sp in spans if sp.name == "tile_dispatch"
+            } == {4}
+    want, _ = _clover_window(_CapturedGblsSession, trace=False)
+    for name in want:
+        np.testing.assert_array_equal(fields[name], want[name], name)
 
 
 class TestCloverLeaf3D:

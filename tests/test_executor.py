@@ -11,8 +11,8 @@ except ImportError:  # pragma: no cover
 
 from repro.core import (
     Arg, Block, INC, OOCConfig, OutOfCoreExecutor, ParallelLoop, READ,
-    ReductionSpec, ReferenceRuntime, ResidentExecutor, RW, Runtime, WRITE,
-    make_dataset, offset_stencil, point_stencil, star_stencil,
+    ReductionSpec, ReferenceRuntime, ResidentExecutor, RW, Runtime, Session,
+    WRITE, make_dataset, offset_stencil, point_stencil, star_stencil,
 )
 
 
@@ -184,3 +184,72 @@ else:
     def test_random_chains_match_reference(spec):
         """Fixed-seed fallback when hypothesis is not installed."""
         _random_chain_body(spec)
+
+
+# -- loop scalars passed as data (gbls) --------------------------------------------
+
+
+def _advect_steps(sess, dts, n=32, m=12):
+    """A two-loop chain per step whose first loop reads the step's ``dt``
+    as a gbl; the field after each step."""
+    blk = Block("g", (n, m))
+    u = make_dataset(blk, "u", halo=1, init=np.random.RandomState(3).rand(
+        n, m).astype(np.float32))
+    t = make_dataset(blk, "t", halo=1)
+    rng = ((1, n - 1), (0, m))
+
+    def advect(acc):
+        dt = acc.gbl("dt")
+        return {"t": acc("u") - dt * (acc("u", (1, 0)) - acc("u", (-1, 0)))}
+
+    out = []
+    for dt in dts:
+        sess.par_loop("advect", blk, rng, [u, t], advect,
+                      gbls={"dt": np.float32(dt)})
+        sess.par_loop("commit", blk, rng, [t, u], lambda acc: {"u": acc("t")})
+        out.append(sess.fetch(u))
+    return out
+
+
+class TestGbls:
+    def test_cached_plan_computes_the_new_value(self):
+        """The second dt replays the first dt's plan and programs, and the
+        tiles read the second dt, not the one the plan was built with."""
+        sess = Session("ooc", num_tiles=2, capacity_bytes=float("inf"))
+        got = _advect_steps(sess, (0.25, 0.125))
+        want = _advect_steps(Session("reference"), (0.25, 0.125))
+        assert sess.plan_stats()["plan_misses"] == 1
+        assert sess.plan_stats()["plan_hits"] == 1
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+
+    def test_adopted_shared_plan_takes_the_adopters_values(self):
+        """A plan another executor built (the serving layer's shared cache)
+        runs with the adopting chain's gbl values."""
+        from repro.serve import SharedPlanCache
+
+        cache = SharedPlanCache()
+        cfg = OOCConfig(num_tiles=2, capacity_bytes=float("inf"))
+        first, second = (Session(backend=OutOfCoreExecutor(
+            cfg, shared_plans=cache)) for _ in range(2))
+        _advect_steps(first, (0.25,))
+        got = _advect_steps(second, (0.125,))
+        assert cache.hits == 1 and second.plan_stats()["plan_misses"] == 0
+        np.testing.assert_array_equal(
+            got[0], _advect_steps(Session("reference"), (0.125,))[0])
+
+    @pytest.mark.parametrize("backend,kw", [
+        ("reference", {}),
+        ("resident", {}),
+        ("ooc", dict(num_tiles=3, capacity_bytes=float("inf"))),
+        ("ooc-cyclic", dict(num_tiles=3, capacity_bytes=float("inf"),
+                            prefetch=True)),
+        ("ooc-sharded", dict(mesh="sim:2", num_tiles=2,
+                             capacity_bytes=float("inf"))),
+    ])
+    def test_gbl_loop_bit_identical_across_backends(self, backend, kw):
+        dts = (0.25, 0.125, 0.0625)
+        got = _advect_steps(Session(backend, **kw), dts)
+        want = _advect_steps(Session("reference"), dts)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)

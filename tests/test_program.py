@@ -23,6 +23,7 @@ from repro.core import (
     point_stencil,
     star_stencil,
 )
+from repro.core.dependency import loop_kernel_fingerprint, plan_signature
 from repro.kernels import star2d_kernel
 
 
@@ -436,3 +437,60 @@ class TestPlanCache:
         assert st6["plan_misses"] == st4["plan_misses"]
         assert st6["plan_hits"] == st4["plan_hits"] + (chains6 - chains4)
         assert st6["plan_hits"] > 0
+
+
+# -- loop scalars passed as data (gbls) --------------------------------------------
+
+
+def _scale_loop(sess, u, value):
+    """One loop that reads its gbl ``s``, recorded as apps record a loop:
+    a fresh closure each call."""
+    def k(acc):
+        return {"u": acc("u") * acc.gbl("s")}
+    sess.par_loop("scale", u.block, u.block.full_range(), [u], k,
+                  gbls={"s": value})
+
+
+class TestGbls:
+    def test_values_share_fingerprint_and_inferred_args(self):
+        sess = Session("reference")
+        blk = Block("g", (8, 4))
+        u = make_dataset(blk, "u", halo=0, init=np.ones((8, 4), np.float32))
+        _scale_loop(sess, u, np.float32(2.0))
+        _scale_loop(sess, u, np.float32(3.0))
+        a, b = sess.queue
+        assert loop_kernel_fingerprint(a) == loop_kernel_fingerprint(b)
+        assert a.args is b.args           # one inference serves both
+        assert plan_signature([a]) == plan_signature([b])
+        other = Session("reference")
+        _scale_loop(other, u, 2.0)        # a new dtype is a new plan
+        assert plan_signature([a]) != plan_signature(other.queue)
+        np.testing.assert_array_equal(sess.fetch(u), 6.0)
+
+    def test_undeclared_gbl_is_a_key_error(self):
+        blk = Block("g", (8, 4))
+        u = make_dataset(blk, "u", halo=0, init=np.ones((8, 4), np.float32))
+        k = lambda acc: {"u": acc("u") * acc.gbl("dt")}
+        with pytest.raises(KeyError, match="dt"):     # at inference
+            Session("reference").par_loop(
+                "scale", blk, blk.full_range(), [u], k, gbls={"s": 1.0})
+        for backend in ("reference", "ooc"):          # declared args: at run
+            sess = Session(backend)
+            sess.par_loop("scale", blk, blk.full_range(),
+                          [Arg(u, point_stencil(2), RW)], k, gbls={"s": 1.0})
+            with pytest.raises(KeyError, match="did not declare"):
+                sess.flush()
+
+    def test_pallas_backend_runs_gbl_loops_on_the_generic_path(self):
+        blk = Block("g", (16, 8))
+        rng = np.random.RandomState(1)
+        u = make_dataset(blk, "u", halo=1,
+                         init=rng.rand(16, 8).astype(np.float32))
+        v = make_dataset(blk, "v", halo=1)
+        sess = Session("pallas")
+        sess.par_loop("sweep", blk, ((1, 15), (1, 7)), [u, v],
+                      star2d_kernel("u", "v", (0.5, 0.125, 0.125)),
+                      gbls={"unused": np.float32(1.0)})
+        sess.flush()
+        assert (sess.backend.pallas_loops, sess.backend.fallback_loops) == (0, 1)
+

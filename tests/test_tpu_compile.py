@@ -89,11 +89,11 @@ def test_cloverleaf_tile_program_compiles(one_chip):
         shape[td] = ln
         slots[name] = _spec(tuple(shape), one_chip)
     scalar = _spec((), one_chip, jnp.int32)
-    starts = {k: scalar for k, box in enumerate(tile.loop_ranges)
-              if box is not None}
+    loop_args = {k: (scalar, {}) for k, box in enumerate(tile.loop_ranges)
+                 if box is not None}
     origins = {name: scalar for name in slots}
     fn = cp.engine.program(tile)
-    compiled = fn.lower(slots, starts, origins).compile()
+    compiled = fn.lower(slots, loop_args, origins).compile()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= sum(
         int(np.prod(s.shape)) * 4 for s in slots.values())
